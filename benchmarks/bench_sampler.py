@@ -86,6 +86,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import ExpertSpec, SamplerConfig, sample_ensemble
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.serve import ServingEngine
 from repro.models import dit as D
 from repro.models.config import dit_b2, router_b2
@@ -919,6 +920,7 @@ def main() -> None:
                          "(every R-th step) samplers; plan_reuse "
                          "sub-merges by R so reruns keep other intervals")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.shards > 1:
         # fail fast on a bad flag BEFORE the ~1 min unsharded benchmark
         if jax.device_count() < args.shards:

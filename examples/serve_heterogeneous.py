@@ -1,8 +1,8 @@
 """Serve a heterogeneous expert ensemble with batched requests.
 
 Loads the self-describing checkpoints written by
-``examples/train_decentralized.py`` (runs it automatically if the
-directory is empty) and serves batched "prompts" through the ServingEngine
+``examples/train_decentralized.py`` (trains them in this process first if
+the directory is empty) and serves batched "prompts" through the ServingEngine
 with the Fig. 2 inference pipeline, reporting latency per strategy.
 
   PYTHONPATH=src python examples/serve_heterogeneous.py --ckpt /tmp/hddm
@@ -77,8 +77,6 @@ behavior is observable via ``engine.stats['cond_cache_hits']`` /
 
 import argparse
 import os
-import subprocess
-import sys
 import tempfile
 import time
 
@@ -88,6 +86,7 @@ import numpy as np
 from repro.core import SamplerConfig
 from repro.launch.serve import ServingEngine
 from repro.models.config import dit_b2, router_b2
+from train_decentralized import train
 
 
 def elastic_walkthrough(steps: int) -> None:
@@ -182,14 +181,7 @@ def main() -> None:
     if not os.path.exists(os.path.join(args.ckpt, "expert0.npz")):
         print(f"no checkpoints under {args.ckpt} — training a tiny "
               "ensemble first ...")
-        subprocess.run(
-            [sys.executable, os.path.join(os.path.dirname(__file__),
-                                          "train_decentralized.py"),
-             "--out", args.ckpt, "--steps", "40"],
-            check=True,
-            env={**os.environ,
-                 "PYTHONPATH": os.environ.get("PYTHONPATH", "src")},
-        )
+        train(args.ckpt, steps=40)
 
     dit_cfg = dit_b2().reduced(latent_size=8)
     rcfg = router_b2(num_clusters=4).reduced(latent_size=8)

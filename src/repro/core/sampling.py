@@ -617,7 +617,9 @@ def _sample_reference(
 ) -> Array:
     x = init_noise if init_noise is not None \
         else jax.random.normal(key, shape, dtype=jnp.float32)
-    ts = jnp.linspace(1.0, 0.0, config.num_steps + 1)
+    # The fused engines' grid bytes: a 1-ulp different t can round to the
+    # neighbouring discrete DiT timestep (round(999·t) at t = 0.5).
+    ts = _time_grid(config.num_steps)
 
     def step(x, i):
         t_hi, t_lo = ts[i], ts[i + 1]

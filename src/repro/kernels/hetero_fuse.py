@@ -9,8 +9,10 @@ the fused kernel reads the K stacked predictions once, applies the
 per-expert schedule coefficients (scalar per expert×sample, broadcast from
 a (K, B) operand), and writes only the fused velocity.
 
-Grid: (B, T/block_t); the expert axis K is kept whole inside the block
-(K ≤ 8 in the paper).
+Grid: (row blocks, T/block_t); the expert axis K is kept whole inside
+the block (K ≤ 8 in the paper).  The hot-path kernels block rows 8 at a
+time (or whole) and carry per-row scalars as ``(..., rows, 1)`` columns,
+the block shapes the TPU lowering accepts.
 
 Four entry points share the module's dispatch policy:
 
@@ -69,24 +71,31 @@ def _fuse_kernel(
     o_ref[0] = fused.astype(o_ref.dtype)
 
 
+def _row_block(rows: int) -> int:
+    """Sublane block over a kernel's row axis: 8 rows (the TPU sublane
+    tile) when they divide the axis, else the whole axis — the two row
+    extents Mosaic lowers for a non-final block dimension."""
+    return 8 if rows % 8 == 0 else rows
+
+
 def _fuse_coeffs_kernel(
     preds_ref, xt_ref, w_ref, coef_ref, o_ref,
     *, clamp: float, alpha_min: float,
 ):
-    preds = preds_ref[:, 0].astype(jnp.float32)       # (K, bt)
-    xt = xt_ref[0].astype(jnp.float32)                # (bt,)
-    w = w_ref[0].astype(jnp.float32)                  # (K,)
-    coef = coef_ref[:, :, 0].astype(jnp.float32)      # (5, K)
+    preds = preds_ref[...].astype(jnp.float32)        # (K, bb, bt)
+    xt = xt_ref[...].astype(jnp.float32)              # (bb, bt)
+    w = w_ref[...].astype(jnp.float32)                # (K, bb, 1)
+    coef = coef_ref[...].astype(jnp.float32)          # (5, K, bb, 1)
     alpha, sigma, dalpha, dsigma, vscale = (
         coef[0], coef[1], coef[2], coef[3], coef[4]
     )
 
-    a_safe = jnp.maximum(alpha, alpha_min)[:, None]
-    x0h = (xt[None] - sigma[:, None] * preds) / a_safe
+    a_safe = jnp.maximum(alpha, alpha_min)
+    x0h = (xt[None] - sigma * preds) / a_safe
     x0h = jnp.clip(x0h, -clamp, clamp)
-    v = (dalpha[:, None] * x0h + dsigma[:, None] * preds) * vscale[:, None]
-    fused = jnp.sum(w[:, None] * v, axis=0)           # (bt,)
-    o_ref[0] = fused.astype(o_ref.dtype)
+    v = (dalpha * x0h + dsigma * preds) * vscale
+    fused = jnp.sum(w * v, axis=0)                    # (bb, bt)
+    o_ref[...] = fused.astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -103,54 +112,58 @@ def hetero_fuse_coeffs(
     block_t: int = 1024,
     interpret: bool = False,
 ) -> Array:
+    """Per-row scalars (weights, coefficients) enter the kernel as
+    ``(..., B, 1)`` columns, so every block's last two dims are a
+    ``(rows, lanes)`` tile the TPU lowering accepts."""
     k, b, t = preds.shape
     block_t = min(block_t, t)
     assert t % block_t == 0
+    bb = _row_block(b)
     kernel = functools.partial(
         _fuse_coeffs_kernel, clamp=clamp, alpha_min=alpha_min
     )
     return pl.pallas_call(
         kernel,
-        grid=(b, t // block_t),
+        grid=(b // bb, t // block_t),
         in_specs=[
-            pl.BlockSpec((k, 1, block_t), lambda bi, ti: (0, bi, ti)),
-            pl.BlockSpec((1, block_t), lambda bi, ti: (bi, ti)),
-            pl.BlockSpec((1, k), lambda bi, ti: (bi, 0)),
-            pl.BlockSpec((5, k, 1), lambda bi, ti: (0, 0, bi)),
+            pl.BlockSpec((k, bb, block_t), lambda bi, ti: (0, bi, ti)),
+            pl.BlockSpec((bb, block_t), lambda bi, ti: (bi, ti)),
+            pl.BlockSpec((k, bb, 1), lambda bi, ti: (0, bi, 0)),
+            pl.BlockSpec((5, k, bb, 1), lambda bi, ti: (0, 0, bi, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_t), lambda bi, ti: (bi, ti)),
+        out_specs=pl.BlockSpec((bb, block_t), lambda bi, ti: (bi, ti)),
         out_shape=jax.ShapeDtypeStruct((b, t), preds.dtype),
         interpret=interpret,
-    )(preds, x_t, weights, coef.astype(jnp.float32))
+        name="hetero_fuse_coeffs",
+    )(preds, x_t, jnp.swapaxes(weights, 0, 1)[..., None],
+      coef.astype(jnp.float32)[..., None])
 
 
 def _fuse_step_kernel(
     preds_ref, xt_ref, w_ref, coef_ref, dt_ref, o_ref,
     *, cfg_scale: float, clamp: float, alpha_min: float,
 ):
-    preds = preds_ref[:, :, 0].astype(jnp.float32)    # (K, G, bt)
-    xt = xt_ref[0].astype(jnp.float32)                # (bt,)
-    w = w_ref[:, 0].astype(jnp.float32)               # (G, K)
-    coef = coef_ref[:, :, :, 0].astype(jnp.float32)   # (5, K, G)
-    dt = dt_ref[0].astype(jnp.float32)
+    preds = preds_ref[...].astype(jnp.float32)        # (K, G, bb, bt)
+    xt = xt_ref[...].astype(jnp.float32)              # (bb, bt)
+    w = w_ref[...].astype(jnp.float32)                # (K, G, bb, 1)
+    coef = coef_ref[...].astype(jnp.float32)          # (5, K, G, bb, 1)
+    dt = dt_ref[...].astype(jnp.float32)              # (bb, 1) or (1, 1)
     g = preds.shape[1]
     alpha, sigma, dalpha, dsigma, vscale = (
         coef[0], coef[1], coef[2], coef[3], coef[4]
-    )                                                 # each (K, G)
+    )                                                 # each (K, G, bb, 1)
 
-    a_safe = jnp.maximum(alpha, alpha_min)[:, :, None]
-    x0h = (xt[None, None] - sigma[:, :, None] * preds) / a_safe
+    a_safe = jnp.maximum(alpha, alpha_min)
+    x0h = (xt[None, None] - sigma * preds) / a_safe
     x0h = jnp.clip(x0h, -clamp, clamp)
-    v = (dalpha[:, :, None] * x0h + dsigma[:, :, None] * preds) \
-        * vscale[:, :, None]
-    wk = jnp.swapaxes(w, 0, 1)[:, :, None]            # (K, G, 1)
-    fused = jnp.sum(wk * v, axis=0)                   # (G, bt)
+    v = (dalpha * x0h + dsigma * preds) * vscale
+    fused = jnp.sum(w * v, axis=0)                    # (G, bb, bt)
     if g == 1:
         u = fused[0]
     else:
         # branch 0 = cond, branch 1 = uncond: u_u + s (u_c − u_u)
         u = fused[1] + cfg_scale * (fused[0] - fused[1])
-    o_ref[0] = (xt - u * dt).astype(o_ref.dtype)
+    o_ref[...] = (xt - u * dt).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -185,19 +198,24 @@ def hetero_fuse_step(
     ``dt`` is either the classic batch-shared ``(1,)`` step size or a
     per-row ``(B,)`` vector — the mixed-timestep rolling-batch case,
     where each resident request sits at its own step of the schedule
-    grid.  Only the BlockSpec index map differs (grid step ``bi`` reads
-    row ``bi`` instead of row 0); the kernel body is identical, so the
-    per-row form is bitwise equal to the scalar form whenever the rows
-    agree.
+    grid.  Only the BlockSpec index map differs (row block ``bi`` reads
+    its own rows instead of the one shared entry); the kernel body is
+    identical, so the per-row form is bitwise equal to the scalar form
+    whenever the rows agree.
+
+    Grid ``(B / bb, T / block_t)`` with ``bb`` 8 rows or the whole batch;
+    the per-row scalars (weights, coefficients, dt) ride along as
+    ``(..., B, 1)`` columns blocked like the rows they scale.
     """
     k, g, b, t = preds.shape
     block_t = min(block_t, t)
     assert t % block_t == 0
     assert dt.shape[0] in (1, b), dt.shape
+    bb = _row_block(b)
     dt_spec = (
-        pl.BlockSpec((1,), lambda bi, ti: (bi,))
+        pl.BlockSpec((bb, 1), lambda bi, ti: (bi, 0))
         if dt.shape[0] == b
-        else pl.BlockSpec((1,), lambda bi, ti: (0,))
+        else pl.BlockSpec((1, 1), lambda bi, ti: (0, 0))
     )
     kernel = functools.partial(
         _fuse_step_kernel,
@@ -205,24 +223,28 @@ def hetero_fuse_step(
     )
     return pl.pallas_call(
         kernel,
-        grid=(b, t // block_t),
+        grid=(b // bb, t // block_t),
         in_specs=[
-            pl.BlockSpec((k, g, 1, block_t), lambda bi, ti: (0, 0, bi, ti)),
-            pl.BlockSpec((1, block_t), lambda bi, ti: (bi, ti)),
-            pl.BlockSpec((g, 1, k), lambda bi, ti: (0, bi, 0)),
-            pl.BlockSpec((5, k, g, 1), lambda bi, ti: (0, 0, 0, bi)),
+            pl.BlockSpec((k, g, bb, block_t),
+                         lambda bi, ti: (0, 0, bi, ti)),
+            pl.BlockSpec((bb, block_t), lambda bi, ti: (bi, ti)),
+            pl.BlockSpec((k, g, bb, 1), lambda bi, ti: (0, 0, bi, 0)),
+            pl.BlockSpec((5, k, g, bb, 1),
+                         lambda bi, ti: (0, 0, 0, bi, 0)),
             dt_spec,
         ],
-        out_specs=pl.BlockSpec((1, block_t), lambda bi, ti: (bi, ti)),
+        out_specs=pl.BlockSpec((bb, block_t), lambda bi, ti: (bi, ti)),
         out_shape=jax.ShapeDtypeStruct((b, t), x_t.dtype),
         interpret=interpret,
-    )(preds, x_t, weights, coef.astype(jnp.float32), dt)
+        name="hetero_fuse_step",
+    )(preds, x_t, jnp.moveaxis(weights, 2, 0)[..., None],
+      coef.astype(jnp.float32)[..., None], dt.reshape(-1, 1))
 
 
 def _dequant_kernel(q_ref, s_ref, o_ref):
-    q = q_ref[0].astype(jnp.float32)                  # (bt,)
-    s = s_ref[0].astype(jnp.float32)                  # per-row scale
-    o_ref[0] = (q * s).astype(o_ref.dtype)
+    q = q_ref[...].astype(jnp.float32)                # (R, bt)
+    s = s_ref[...].astype(jnp.float32)                # (R, 1) row scales
+    o_ref[...] = (q * s).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -242,22 +264,25 @@ def hetero_fuse_dequant(
     static expert slice, or the full ``K`` stack (off-hot-path
     materialize).  The scale broadcast happens inside the kernel, so the
     quantized bytes are read once and only the compute-precision result
-    is written.
+    is written.  Blocks hold every row (a handful of experts) — whole-axis
+    row blocks lower for any row count and any storage dtype's sublane
+    packing.
     """
     r, t = q.shape
     block_t = min(block_t, t)
     assert t % block_t == 0
     return pl.pallas_call(
         _dequant_kernel,
-        grid=(r, t // block_t),
+        grid=(t // block_t,),
         in_specs=[
-            pl.BlockSpec((1, block_t), lambda ri, ti: (ri, ti)),
-            pl.BlockSpec((1,), lambda ri, ti: (ri,)),
+            pl.BlockSpec((r, block_t), lambda ti: (0, ti)),
+            pl.BlockSpec((r, 1), lambda ti: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_t), lambda ri, ti: (ri, ti)),
+        out_specs=pl.BlockSpec((r, block_t), lambda ti: (0, ti)),
         out_shape=jax.ShapeDtypeStruct((r, t), out_dtype),
         interpret=interpret,
-    )(q, scale)
+        name="hetero_fuse_dequant",
+    )(q, scale.reshape(r, 1))
 
 
 @functools.partial(

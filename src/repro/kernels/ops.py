@@ -1,10 +1,10 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-Dispatch policy: on TPU backends the compiled Pallas kernels run natively;
-elsewhere (this CPU container, unit tests) the same kernel bodies execute
-under ``interpret=True``, and callers that need speed on CPU use the
-pure-jnp reference paths in the model code.  ``use_pallas()`` is the single
-switch, overridable via REPRO_FORCE_PALLAS=0/1.
+Dispatch policy: on TPU backends the compiled Pallas kernels always run
+natively.  Elsewhere the pure-jnp oracles run by default, and
+``REPRO_FORCE_PALLAS=1`` runs the same kernel bodies under
+``interpret=True`` instead (the kernel-parity tests).  ``use_pallas()`` is
+the single switch; nothing turns the kernels off on a TPU.
 """
 
 from __future__ import annotations
@@ -14,6 +14,7 @@ import os
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core.conversion import ConversionConfig, velocity_scale
 from repro.core.schedules import Schedule
@@ -35,14 +36,28 @@ def on_tpu() -> bool:
 
 
 def use_pallas() -> bool:
-    env = os.environ.get("REPRO_FORCE_PALLAS")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return on_tpu()
+    return on_tpu() or os.environ.get("REPRO_FORCE_PALLAS") == "1"
 
 
 def _interpret() -> bool:
     return not on_tpu()
+
+
+def _launch(kernel, *args):
+    """Run one Pallas launch, replicated across the ambient mesh.
+
+    Mosaic kernels cannot be partitioned by the compiler.  Inside a
+    multi-device mesh (sharded serving traces its programs under
+    ``jax.sharding.use_abstract_mesh``) the launch therefore runs under
+    ``shard_map`` with every operand replicated: each device gathers the
+    operands and runs the whole launch.  Without a mesh it is a plain
+    call.
+    """
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1:
+        return kernel(*args)
+    return jax.shard_map(kernel, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)(*args)
 
 
 # --- flash attention -------------------------------------------------------
@@ -116,10 +131,10 @@ def fused_velocity(
     pf = preds.reshape(k, b, tsize)
     xf = x_t.reshape(b, tsize)
     if use_pallas():
-        out = _hetero_fuse_coeffs(
-            pf, xf, weights, coef,
+        out = _launch(functools.partial(
+            _hetero_fuse_coeffs,
             clamp=clamp, alpha_min=alpha_min, interpret=_interpret(),
-        )
+        ), pf, xf, weights, coef)
     else:
         out = _ref.ref_hetero_fuse_coeffs(
             pf, xf, weights, coef, clamp=clamp, alpha_min=alpha_min,
@@ -197,11 +212,11 @@ def fused_step(
             pad = ((0, 0), (0, 0), (0, 0), (0, tp - t))
             pf = jnp.pad(pf, pad)
             xf = jnp.pad(xf, ((0, 0), (0, tp - t)))
-        out = _hetero_fuse_step(
-            pf, xf, wf, cf, dt,
+        out = _launch(functools.partial(
+            _hetero_fuse_step,
             cfg_scale=cfg_scale, clamp=clamp, alpha_min=alpha_min,
             block_t=block, interpret=_interpret(),
-        )[:, :t]
+        ), pf, xf, wf, cf, dt)[:, :t]
     else:
         out = _ref.ref_hetero_fuse_step(
             pf, xf, wf, cf, dt,
@@ -233,10 +248,10 @@ def dequant_params(
         tp, block = _tile_pad(t)
         if tp != t:
             qf = jnp.pad(qf, ((0, 0), (0, tp - t)))
-        out = _hetero_fuse_dequant(
-            qf, scale, out_dtype=out_dtype, block_t=block,
+        out = _launch(functools.partial(
+            _hetero_fuse_dequant, out_dtype=out_dtype, block_t=block,
             interpret=_interpret(),
-        )[:, :t]
+        ), qf, scale)[:, :t]
     else:
         out = _ref.ref_hetero_fuse_dequant(qf, scale, out_dtype=out_dtype)
     return out.reshape((rows,) + trailing)
@@ -245,6 +260,12 @@ def dequant_params(
 #: max rows per ragged-GEMM tile — whole per-group row blocks halve down
 #: to at most this many rows so tiles stay VMEM-friendly.
 _RAGGED_BLOCK_M = 256
+
+#: VMEM one ragged-GEMM grid step may hold.  The default scoped-VMEM
+#: limit of a v5e kernel is 16 MiB; the rest is left to Mosaic's own
+#: scratch.  Deep contractions (the MLP down-projection, 3072→768 at
+#: dit-b2 widths) overflow it with a whole-width output tile.
+_RAGGED_VMEM_BUDGET = 12 * 2**20
 
 
 def ragged_block_m(m: int) -> int | None:
@@ -265,6 +286,46 @@ def ragged_block_m(m: int) -> int | None:
     return bm
 
 
+def _ragged_step_bytes(bm: int, d: int, bf: int, x_bytes: int,
+                       w_bytes: int, quantized: bool) -> int:
+    """VMEM held by one ragged-GEMM grid step: the double-buffered x, w
+    and out blocks, the f32 accumulator and epilogue, and (dense body
+    only) the f32 upcasts of operands stored narrower than f32."""
+    held = 2 * (bm * d * x_bytes + d * bf * w_bytes + bm * bf * 4)
+    held += 2 * bm * bf * 4
+    if not quantized:
+        held += (x_bytes < 4) * bm * d * 4 + (w_bytes < 4) * d * bf * 4
+    return held
+
+
+def ragged_tiles(m: int, d: int, f: int, x_bytes: int, w_bytes: int,
+                 quantized: bool) -> tuple[int, int, int] | None:
+    """``(block_m, padded F, block_f)`` for a ragged GEMM, or ``None``.
+
+    Rows start from :func:`ragged_block_m`; output lanes pad by the
+    shared :func:`_tile_pad` policy, and the block is the widest
+    lane-multiple divisor of the padded width (at most ``_TILE_BLOCK``)
+    whose grid step fits ``_RAGGED_VMEM_BUDGET`` at contraction depth
+    ``d``.  When even a one-lane-tile block overflows, the row block
+    halves (staying a multiple of 8).  ``None`` — row groups that cannot
+    tile — sends the caller to the dense-math fallback.
+    """
+    bm = ragged_block_m(m)
+    if bm is None:
+        return None
+    fp, _ = _tile_pad(f)
+    lane, _ = _tile_pad(1)
+    while True:
+        for bf in range(min(fp, _TILE_BLOCK), 0, -lane):
+            if fp % bf == 0 and _ragged_step_bytes(
+                bm, d, bf, x_bytes, w_bytes, quantized
+            ) <= _RAGGED_VMEM_BUDGET:
+                return bm, fp, bf
+        if bm % 16:
+            return None
+        bm //= 2
+
+
 def ragged_expert_matmul(
     x: Array,                 # (P, ..., D) per-group activations
     w: Array,                 # (K, D, F) stacked expert weights (or quant)
@@ -282,8 +343,8 @@ def ragged_expert_matmul(
     one op, empty segments costing nothing.
 
     On the Pallas path the groups flatten to ``(P·m, D)`` tile-aligned
-    rows for :func:`repro.kernels.ragged_gemm.ragged_gemm` (output lanes
-    pad via the shared ``_tile_pad`` policy and slice back); quantized
+    rows for :func:`repro.kernels.ragged_gemm.ragged_gemm` (tiles from
+    :func:`ragged_tiles`; output lanes pad and slice back); quantized
     weights (int8 / fp8, with ``w_scale``) keep their storage dtype all
     the way to the MXU — activations quantize per row symmetrically to
     the same storage format and the kernel fuses the
@@ -309,10 +370,11 @@ def ragged_expert_matmul(
         raise ValueError("quantized ragged_expert_matmul needs w_scale")
     expert_ids = expert_ids.astype(jnp.int32)
 
-    bm = ragged_block_m(m)
-    if use_pallas() and bm is not None:
+    x_bytes = w.dtype.itemsize if quantized else x.dtype.itemsize
+    tiles = ragged_tiles(m, d, f, x_bytes, w.dtype.itemsize, quantized)
+    if use_pallas() and tiles is not None:
+        bm, fp, bf = tiles
         xf = x.reshape(p * m, d)
-        fp, bf = _tile_pad(f)
         wp = jnp.pad(w, ((0, 0), (0, 0), (0, fp - f))) if fp != f else w
         tile_e = jnp.repeat(expert_ids, m // bm)
         if quantized:
@@ -324,11 +386,15 @@ def ragged_expert_matmul(
                 xq = jnp.clip(jnp.round(xq), -127, 127).astype(jnp.int8)
             else:
                 xq = xq.astype(jnp.float8_e4m3fn)
-            y = _ragged_gemm(xq, wp, tile_e, xs, w_scale,
-                             block_m=bm, block_f=bf, interpret=_interpret())
+            y = _launch(functools.partial(
+                _ragged_gemm, block_m=bm, block_f=bf,
+                interpret=_interpret(),
+            ), xq, wp, tile_e, xs, w_scale)
         else:
-            y = _ragged_gemm(xf, wp, tile_e, None, None,
-                             block_m=bm, block_f=bf, interpret=_interpret())
+            y = _launch(functools.partial(
+                _ragged_gemm, block_m=bm, block_f=bf,
+                interpret=_interpret(),
+            ), xf, wp, tile_e)
         y = y[:, :f].reshape((p,) + mids + (f,))
     else:
         if quantized:

@@ -20,10 +20,11 @@ leaf — the Megablocks-style grouped-GEMM economy, applied to the
   quantization buys compute, not just resident bytes.
 
 Tile geometry (``block_m`` rows × ``block_f`` output lanes, full-depth
-contraction) is decided by the ``ops.ragged_expert_matmul`` wrapper from
-the shared ``_tile_pad`` policy; this module never hard-codes lane
-arithmetic.  ``debug=True`` adds a per-grid-step tile counter output so
-tests can *measure* that empty segments cost zero tiles.
+contraction) is decided by the ``ops.ragged_expert_matmul`` wrapper
+(``ops.ragged_tiles``: lane padding plus a VMEM budget that counts the
+contraction depth); this module never hard-codes lane arithmetic.
+``debug=True`` adds a per-grid-step tile counter output so tests can
+*measure* that empty segments cost zero tiles.
 """
 
 from __future__ import annotations
@@ -94,7 +95,9 @@ def ragged_gemm(
 
     ``debug=True`` returns ``(y, tiles)`` where ``tiles`` is an
     ``(M/block_m, F/block_f)`` int32 map with a 1 per executed grid
-    step — the runtime proof that empty segments cost zero tiles.
+    step — the runtime proof that empty segments cost zero tiles.  Each
+    grid step writes a whole ``(8, 128)`` counter tile (the smallest
+    int32 block the TPU lowering accepts); the map is its corners.
     """
     m, d = x.shape
     k_cap, dw, f = w.shape
@@ -119,8 +122,10 @@ def ragged_gemm(
         pl.BlockSpec((block_m, block_f), lambda i, j, *pf: (i, j))
     ]
     if debug:
-        out_shape.append(jax.ShapeDtypeStruct((gm, gf), jnp.int32))
-        out_specs.append(pl.BlockSpec((1, 1), lambda i, j, *pf: (i, j)))
+        out_shape.append(
+            jax.ShapeDtypeStruct((gm * 8, gf * 128), jnp.int32)
+        )
+        out_specs.append(pl.BlockSpec((8, 128), lambda i, j, *pf: (i, j)))
 
     tile_experts = tile_experts.astype(jnp.int32)
     if quantized:
@@ -147,7 +152,7 @@ def ragged_gemm(
         )
         out = pl.pallas_call(
             body, grid_spec=grid_spec, out_shape=out_shape,
-            interpret=interpret,
+            interpret=interpret, name="ragged_gemm",
         )(tile_experts, w_scale.astype(jnp.float32),
           x, x_scale.astype(jnp.float32).reshape(m, 1), w)
     else:
@@ -162,6 +167,8 @@ def ragged_gemm(
         )
         out = pl.pallas_call(
             _dense_body, grid_spec=grid_spec, out_shape=out_shape,
-            interpret=interpret,
+            interpret=interpret, name="ragged_gemm",
         )(tile_experts, x, w)
-    return tuple(out) if debug else out[0]
+    if debug:
+        return out[0], out[1][::8, ::128]
+    return out[0]

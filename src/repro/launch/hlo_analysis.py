@@ -103,9 +103,6 @@ def compiled_bytes_accessed(compiled) -> float:
         ca = compiled.cost_analysis()
     except Exception:
         return 0.0
-    # Older jax versions return a one-element list of dicts.
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
     if not isinstance(ca, dict):
         return 0.0
     return float(ca.get("bytes accessed", 0.0))

@@ -18,17 +18,25 @@ from __future__ import annotations
 
 import jax
 import numpy as np
+from jax.sharding import AxisType
+
+
+def _auto(n: int) -> tuple:
+    """Auto (compiler-propagated) axes: the serving code places arrays with
+    ``NamedSharding`` + ``with_sharding_constraint``, which Explicit axes
+    (``jax.make_mesh``'s default) reject."""
+    return (AxisType.Auto,) * n
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_host_mesh():
     """1-device mesh for CPU tests (same axis names as single-pod)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"), axis_types=_auto(2))
 
 
 def make_expert_mesh(n_expert_shards: int = 1, n_data_shards: int | None = None):
@@ -61,11 +69,12 @@ def make_expert_mesh(n_expert_shards: int = 1, n_data_shards: int | None = None)
         )
     if need == ndev:
         return jax.make_mesh((n_expert_shards, n_data_shards),
-                             ("expert", "data"))
+                             ("expert", "data"), axis_types=_auto(2))
     devices = np.asarray(jax.devices()[:need]).reshape(
         n_expert_shards, n_data_shards
     )
-    return jax.sharding.Mesh(devices, ("expert", "data"))
+    return jax.sharding.Mesh(devices, ("expert", "data"),
+                             axis_types=_auto(2))
 
 
 def data_axes(mesh) -> tuple[str, ...]:

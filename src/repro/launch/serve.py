@@ -114,10 +114,13 @@ from repro.core import (
     params_are_stackable,
     sample_ensemble,
 )
+from repro.core.sampling import _resolve_engine
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_expert_mesh
 from repro.launch.sharding import (
     dispatch_plan_sharding,
     expert_param_shardings,
+    mesh_scope,
     serve_batch_spec,
 )
 from repro.models import dit as D
@@ -385,16 +388,31 @@ class ServingEngine:
             self._slot_template = (
                 treedef, [tuple(np.shape(leaf)) for leaf in leaves]
             )
+        #: dispatches run routed over the stacked store and never read
+        #: the per-expert list
+        self._store_only = (self.param_store is not None
+                            and self._resolves_routed())
         if quantized:
             # The quantized store IS the resident representation: drop
             # the full-precision per-expert list so the ~4x byte saving
             # is real, not an extra copy.  (The dense fallback and the
             # reference engine need that list; they raise clearly.)
             self.expert_params = None
+        elif self._store_only:
+            # The per-expert list (what the reference and dense engines
+            # read) stays in host memory, so the device holds the
+            # weights once, not twice.
+            self.expert_params = jax.device_get(self.expert_params)
         self.expert_health = ["ACTIVE"] * len(self.experts)
         self.membership_epoch = 0
         if self.elastic:
             self._init_elastic()
+        if self.router_fn is not None and not isinstance(
+            self.router_fn, jax.tree_util.Partial
+        ):
+            # A pytree callable can be a jit argument (its bound arrays,
+            # if any, are traced rather than baked into the program).
+            self.router_fn = jax.tree_util.Partial(self.router_fn)
         self.mesh = None
         if self.n_expert_shards != 1 or self.n_data_shards is not None:
             if self.n_expert_shards > 1 and \
@@ -411,6 +429,9 @@ class ServingEngine:
                                          self.n_data_shards)
             if self.param_store is not None:
                 self.param_store = self._put_store(self.param_store)
+            self.router_fn = jax.device_put(
+                self.router_fn, NamedSharding(self.mesh, P())
+            )
 
     def _put_store(self, store):
         """Place a store on the expert mesh (no-op unsharded).
@@ -1043,14 +1064,59 @@ class ServingEngine:
 
     # -- retrace-free compiled-sampler cache --------------------------------
 
+    def _sampler_args(self, membership: tuple | None = None) -> tuple:
+        """What a compiled sampler reads besides the request, as jit
+        arguments: ``(expert params, store, router, coeff tables,
+        cluster map)``.
+
+        Closed over instead, every array would be embedded in the XLA
+        program as a constant — at dit-b2 widths gigabytes of weights
+        inside the HLO.  ``membership`` is an admission-time snapshot
+        ``(epoch, store, tables, cmap)``; ``None`` means the current one
+        (a fixed-membership engine has no tables or map).  The
+        per-expert params list rides along only where the resolved
+        engine runs it (dense / reference, or routed without a store).
+        """
+        if membership is None and self.elastic:
+            membership = self._membership()
+        if membership is None:
+            store, tables, cmap = self.param_store, None, None
+        else:
+            _, store, tables, cmap = membership
+        params = None if self._store_only else self.expert_params
+        return params, store, self.router_fn, tables, cmap
+
+    def _resolves_routed(self) -> bool:
+        """Whether dispatches resolve to the routed engine; False for a
+        misconfigured engine, whose first dispatch raises the error."""
+        try:
+            return _resolve_engine(self.engine, self.experts,
+                                   self.expert_params,
+                                   self.sampler) == "routed"
+        except ValueError:
+            return False
+
+    def _sampler_arg_shardings(self) -> list:
+        """Mesh shardings of :meth:`_sampler_args`, in order."""
+        rep = NamedSharding(self.mesh, P())
+        store = rep if self.param_store is None else expert_param_shardings(
+            self.param_store, self.mesh,
+            logical_axes=self.param_store.logical_axes(),
+        )
+        return [rep, store, rep, rep, rep]
+
     def _get_compiled(self, batch_size: int, has_text: bool) -> Callable:
         """Jitted sampler keyed by everything that changes the trace.
 
-        The initial-noise buffer is donated — XLA reuses it for the
-        evolving latent state instead of allocating a fresh buffer per
-        request.  On a sharded engine the noise/text inputs carry
-        explicit "data"-axis shardings and the latent state is pinned to
-        them throughout the scan.
+        Called as ``fn(key, noise, text, *self._sampler_args(...))``.
+        Elastic engines' membership substrate — store (with its validity
+        mask), coefficient tables, cluster map — is argument *data*, so
+        every epoch hits the same compiled fn (shapes are
+        capacity-stable).  The initial-noise buffer is donated — XLA
+        reuses it for the evolving latent state instead of allocating a
+        fresh buffer per request.  On a sharded engine the noise/text
+        inputs carry explicit "data"-axis shardings and the latent state
+        is pinned to them throughout the scan.
         """
         cache_key = (batch_size, self.latent_shape, self.sampler,
                      self.engine, has_text)
@@ -1066,36 +1132,21 @@ class ServingEngine:
                 plan_sharding = dispatch_plan_sharding(self.mesh)
                 batch_sharded = len(lat_spec) > 0 and lat_spec[0] is not None
                 text_spec = P("data") if (has_text and batch_sharded) else P()
-                in_shardings = [
+                jit_kwargs["in_shardings"] = (
                     NamedSharding(self.mesh, P()),        # PRNG key
                     latent_sharding,                      # initial noise
                     NamedSharding(self.mesh, text_spec),  # text embeddings
-                ]
-                if self.elastic:
-                    in_shardings += [
-                        expert_param_shardings(
-                            self.param_store, self.mesh,
-                            logical_axes=self.param_store.logical_axes(),
-                        ),                                # membership store
-                        NamedSharding(self.mesh, P()),    # coeff tables
-                        NamedSharding(self.mesh, P()),    # cluster map
-                    ]
-                jit_kwargs["in_shardings"] = tuple(in_shardings)
+                    *self._sampler_arg_shardings(),
+                )
 
-            if self.elastic:
-                # Elastic engines take the membership substrate — store
-                # (with its validity mask), coefficient tables, cluster
-                # map — as jit ARGUMENTS: closing over them would bake
-                # membership into the trace as constants, forcing a
-                # recompile per add/evict.  Shapes are capacity-stable,
-                # so every epoch hits the same compiled fn.
-                def _sample(key, noise, text_emb, store, tables, cmap):
-                    self.stats["traces"] += 1  # runs at trace time only
-                    cond = {"text_emb": text_emb} if has_text else None
-                    null = {"text_emb": None} if has_text else None
+            def _sample(key, noise, text_emb, params, store, router_fn,
+                        tables, cmap):
+                self.stats["traces"] += 1  # runs at trace time only
+                cond = {"text_emb": text_emb} if has_text else None
+                null = {"text_emb": None} if has_text else None
+                with mesh_scope(self.mesh):
                     return sample_ensemble(
-                        key, self.experts, self.expert_params,
-                        self.router_fn,
+                        key, self.experts, params, router_fn,
                         shape, cond=cond, null_cond=null,
                         config=self.sampler,
                         engine=self.engine, init_noise=noise,
@@ -1103,21 +1154,6 @@ class ServingEngine:
                         latent_sharding=latent_sharding,
                         plan_sharding=plan_sharding,
                         coeff_tables=tables, cluster_map=cmap,
-                    )
-            else:
-                def _sample(key, noise, text_emb):
-                    self.stats["traces"] += 1  # runs at trace time only
-                    cond = {"text_emb": text_emb} if has_text else None
-                    null = {"text_emb": None} if has_text else None
-                    return sample_ensemble(
-                        key, self.experts, self.expert_params,
-                        self.router_fn,
-                        shape, cond=cond, null_cond=null,
-                        config=self.sampler,
-                        engine=self.engine, init_noise=noise,
-                        stacked_params=self.param_store,
-                        latent_sharding=latent_sharding,
-                        plan_sharding=plan_sharding,
                     )
 
             # donation is a no-op (with a warning) on CPU; only request it
@@ -1132,13 +1168,10 @@ class ServingEngine:
 
         ``membership`` is an admission-time snapshot tuple for queued
         requests; ``None`` means current membership (``generate``)."""
-        if not self.elastic:
-            return fn(key, noise, text)
-        if membership is None:
-            membership = self._membership()
-        _, store, tables, cmap = membership
-        self._note_degraded(store)
-        return fn(key, noise, text, store, tables, cmap)
+        args = self._sampler_args(membership)
+        if self.elastic:
+            self._note_degraded(args[1])
+        return fn(key, noise, text, *args)
 
     def generate(
         self, key, batch_text_emb: jnp.ndarray | None, batch_size: int,
@@ -1323,6 +1356,17 @@ class ServingEngine:
             off += r.batch_size
 
 
+def serve_configs(reduced: bool,
+                  latent_size: int) -> tuple[DiTConfig, DiTConfig]:
+    """The CLI's expert and router configs: dit-b2 / router-b2 at the
+    published widths, or their reduced smoke preset at ``latent_size``."""
+    dit_cfg, rcfg = dit_b2(), router_b2()
+    if reduced:
+        dit_cfg = dit_cfg.reduced(latent_size=latent_size)
+        rcfg = rcfg.reduced(latent_size=latent_size)
+    return dit_cfg, rcfg
+
+
 def main() -> None:
     ap = argparse.ArgumentParser(
         epilog="shards > 1 need that many visible devices — on a CPU host "
@@ -1369,8 +1413,13 @@ def main() -> None:
                     help="cross-request conditioning LRU capacity "
                          "(content-hash-keyed text-embedding reuse "
                          "across submit()/generate() calls; 0 disables)")
-    ap.add_argument("--reduced", action="store_true", default=True)
-    ap.add_argument("--latent-size", type=int, default=8)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="serve the 2-layer d=128 smoke preset of dit-b2 "
+                         "(--no-reduced: the published widths, 32x32x4 "
+                         "latents)")
+    ap.add_argument("--latent-size", type=int, default=8,
+                    help="latent side of the --reduced preset")
     ap.add_argument("--expert-shards", type=int, default=1)
     ap.add_argument("--data-shards", type=int, default=None)
     ap.add_argument("--coalesce", action="store_true",
@@ -1419,12 +1468,9 @@ def main() -> None:
                          "per step after serving (grouped bucket-padding "
                          "tax; 0.0 under --dispatch ragged)")
     args = ap.parse_args()
+    enable_compile_cache()
 
-    dit_cfg = dit_b2()
-    rcfg = router_b2()
-    if args.reduced:
-        dit_cfg = dit_cfg.reduced(latent_size=args.latent_size)
-        rcfg = rcfg.reduced(latent_size=args.latent_size)
+    dit_cfg, rcfg = serve_configs(args.reduced, args.latent_size)
     engine = ServingEngine.from_checkpoint_dir(
         args.ckpt_dir, dit_cfg=dit_cfg, router_cfg=rcfg,
         sampler=SamplerConfig(

@@ -18,6 +18,7 @@ the assigned configs is divisible by 16 regardless.
 
 from __future__ import annotations
 
+import contextlib
 from typing import Any
 
 import jax
@@ -317,6 +318,19 @@ def dispatch_plan_sharding(mesh: Mesh) -> NamedSharding:
     bucket branch (grouped) or every weight gather (ragged).
     """
     return NamedSharding(mesh, P())
+
+
+def mesh_scope(mesh: Mesh | None):
+    """Context a served program is traced in when it runs on ``mesh``.
+
+    The hot-path kernels (``kernels.ops``) look for this ambient mesh:
+    the TPU compiler cannot partition a Pallas launch, so on a
+    multi-device mesh each launch runs under ``shard_map``.  No mesh —
+    no context.
+    """
+    if mesh is None:
+        return contextlib.nullcontext()
+    return jax.sharding.use_abstract_mesh(mesh.abstract_mesh)
 
 
 def serve_batch_spec(mesh: Mesh, shape: tuple[int, ...]) -> P:

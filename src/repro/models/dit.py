@@ -24,6 +24,7 @@ adaln_single / cross_attn / text_proj / final_layer / null_text_embed.
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -574,11 +575,17 @@ def make_expert_apply(cfg: DiTConfig):
     return apply_fn
 
 
+def _router_posterior(cfg: DiTConfig, params, x_t, t):
+    return jax.nn.softmax(apply(cfg, params, x_t, t), axis=-1)
+
+
 def make_router_fn(cfg: DiTConfig, params):
-    """Router posterior p(k | x_t, t) (Eq. 2)."""
+    """Router posterior p(k | x_t, t) (Eq. 2), as ``router_fn(x_t, t)``.
 
-    def router_fn(x_t, t):
-        logits = apply(cfg, params, x_t, t)
-        return jax.nn.softmax(logits, axis=-1)
-
-    return router_fn
+    A ``jax.tree_util.Partial`` whose leaves are the router params, so a
+    jitted sampler takes it as an argument: the weights stay traced
+    buffers instead of constants baked into the program.
+    """
+    return jax.tree_util.Partial(
+        functools.partial(_router_posterior, cfg), params
+    )

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import ExpertSpec, SamplerConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.serve import ServingEngine
 from repro.serving import ContinuousScheduler
 
@@ -70,6 +71,7 @@ def _make_engine() -> ServingEngine:
 
 
 def main() -> int:
+    enable_compile_cache()
     engine = _make_engine()
     sched = ContinuousScheduler(engine, max_resident=4)
 

@@ -641,8 +641,9 @@ class ResilientScheduler(ContinuousScheduler):
                 key, (1,) + eng.latent_shape, jnp.float32
             )
             out = fn(key, noise, jnp.zeros((0,), jnp.float32),
-                     store.with_valid(onehot), eng._coeff_tables,
-                     eng._cluster_map)
+                     *eng._sampler_args((None, store.with_valid(onehot),
+                                         eng._coeff_tables,
+                                         eng._cluster_map)))
             return bool(np.isfinite(np.asarray(out)).all())
         except Exception:            # noqa: BLE001 — a crashing probe fails
             return False

@@ -63,10 +63,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import sample_ensemble_step
-from repro.launch.sharding import (
-    expert_param_shardings,
-    rolling_state_shardings,
-)
+from repro.launch.sharding import mesh_scope, rolling_state_shardings
 from repro.serving.batch import RollingBatch, draw_noise
 from repro.serving.metrics import LatencyRecorder, RequestTiming
 
@@ -349,14 +346,11 @@ class ContinuousScheduler:
         fn = self._get_rolling_compiled(has_text, bucket.text_tail)
         text = bucket.text if has_text \
             else jnp.zeros((0,), jnp.float32)            # static filler
+        args = eng._sampler_args(bucket.membership)
         if eng.elastic:
-            _, store, tables, cmap = bucket.membership
-            eng._note_degraded(store, steps=1)
-            out = fn(bucket.x, bucket.t_idx, bucket.slot_idx,
-                     bucket.slot_w, text, store, tables, cmap)
-        else:
-            out = fn(bucket.x, bucket.t_idx, bucket.slot_idx,
-                     bucket.slot_w, text)
+            eng._note_degraded(args[1], steps=1)
+        out = fn(bucket.x, bucket.t_idx, bucket.slot_idx, bucket.slot_w,
+                 text, *args)
         bucket.x, bucket.t_idx, bucket.slot_idx, bucket.slot_w = out
         bucket.advance_host(self.steps_per_tick)
 
@@ -386,23 +380,14 @@ class ContinuousScheduler:
             lat_spec = latent_sharding.spec
             batch_sharded = len(lat_spec) > 0 and lat_spec[0] is not None
             text_spec = P("data") if (has_text and batch_sharded) else P()
-            in_shardings = [
+            jit_kwargs["in_shardings"] = (
                 latent_sharding,                      # x
                 row_state,                            # t_idx
                 row_state,                            # slot_idx
                 row_state,                            # slot_w
                 NamedSharding(eng.mesh, text_spec),   # text
-            ]
-            if eng.elastic:
-                in_shardings += [
-                    expert_param_shardings(
-                        eng.param_store, eng.mesh,
-                        logical_axes=eng.param_store.logical_axes(),
-                    ),                                # membership store
-                    NamedSharding(eng.mesh, P()),     # coeff tables
-                    NamedSharding(eng.mesh, P()),     # cluster map
-                ]
-            jit_kwargs["in_shardings"] = tuple(in_shardings)
+                *eng._sampler_arg_shardings(),
+            )
 
         spt = self.steps_per_tick
 
@@ -429,44 +414,25 @@ class ContinuousScheduler:
             )
             return carry
 
-        if eng.elastic:
-            def _step(x, t_idx, slot_idx, slot_w, text, store, tables,
-                      cmap):
-                eng.stats["traces"] += 1   # runs at trace time only
-                cond = {"text_emb": text} if has_text else None
-                null = {"text_emb": None} if has_text else None
+        def _step(x, t_idx, slot_idx, slot_w, text, params, store,
+                  router_fn, tables, cmap):
+            eng.stats["traces"] += 1   # runs at trace time only
+            cond = {"text_emb": text} if has_text else None
+            null = {"text_emb": None} if has_text else None
 
-                def one_step(carry):
-                    x, t_idx, slot_idx, slot_w = carry
-                    return sample_ensemble_step(
-                        eng.experts, eng.expert_params, eng.router_fn,
-                        x, t_idx, slot_idx, slot_w,
-                        cond=cond, null_cond=null, config=eng.sampler,
-                        engine=eng.engine, stacked_params=store,
-                        latent_sharding=latent_sharding,
-                        plan_sharding=plan_sharding,
-                        coeff_tables=tables, cluster_map=cmap,
-                    )
+            def one_step(carry):
+                x, t_idx, slot_idx, slot_w = carry
+                return sample_ensemble_step(
+                    eng.experts, params, router_fn,
+                    x, t_idx, slot_idx, slot_w,
+                    cond=cond, null_cond=null, config=eng.sampler,
+                    engine=eng.engine, stacked_params=store,
+                    latent_sharding=latent_sharding,
+                    plan_sharding=plan_sharding,
+                    coeff_tables=tables, cluster_map=cmap,
+                )
 
-                return _tick(one_step, x, t_idx, slot_idx, slot_w)
-        else:
-            def _step(x, t_idx, slot_idx, slot_w, text):
-                eng.stats["traces"] += 1   # runs at trace time only
-                cond = {"text_emb": text} if has_text else None
-                null = {"text_emb": None} if has_text else None
-
-                def one_step(carry):
-                    x, t_idx, slot_idx, slot_w = carry
-                    return sample_ensemble_step(
-                        eng.experts, eng.expert_params, eng.router_fn,
-                        x, t_idx, slot_idx, slot_w,
-                        cond=cond, null_cond=null, config=eng.sampler,
-                        engine=eng.engine,
-                        stacked_params=eng.param_store,
-                        latent_sharding=latent_sharding,
-                        plan_sharding=plan_sharding,
-                    )
-
+            with mesh_scope(eng.mesh):
                 return _tick(one_step, x, t_idx, slot_idx, slot_w)
 
         # The latent buffer is donated (aliased into the step output);

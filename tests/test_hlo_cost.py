@@ -103,7 +103,7 @@ def test_bytes_accessed_raising_backend_degrades_to_zero():
 
 
 def test_bytes_accessed_empty_cost_analysis_list():
-    """Older jax: cost_analysis() -> [] (no properties reported)."""
+    """A payload that is not a properties dict reports nothing."""
     assert compiled_bytes_accessed(_FakeCompiled([])) == 0.0
 
 
@@ -119,7 +119,9 @@ def test_bytes_accessed_non_dict_payload_degrades_to_zero():
 
 
 def test_bytes_accessed_reads_key_old_and_new_shapes():
+    """The installed jax returns a dict; the list-of-dicts shape of
+    older releases is no longer read."""
     assert compiled_bytes_accessed(
         _FakeCompiled({"bytes accessed": 42.0})) == 42.0
     assert compiled_bytes_accessed(
-        _FakeCompiled([{"bytes accessed": 7.0}])) == 7.0
+        _FakeCompiled([{"bytes accessed": 7.0}])) == 0.0

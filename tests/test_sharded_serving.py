@@ -103,6 +103,56 @@ def test_multi_shard_parity_toy_two_devices():
     assert '"devices": 2' in proc.stdout
 
 
+_MESH_KERNELS = """
+import jax, jax.numpy as jnp, numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.kernels import ops
+from repro.launch.mesh import make_expert_mesh
+from repro.launch.sharding import mesh_scope
+
+mesh = make_expert_mesh(2, 1)
+k = jax.random.split(jax.random.PRNGKey(0), 6)
+x = jax.random.normal(k[0], (4, 16, 32))
+w = jax.random.normal(k[1], (4, 32, 40))
+e = jnp.array([0, 3, 1, 3], jnp.int32)
+preds = jax.random.normal(k[2], (2, 4, 4, 4, 2))
+xt = jax.random.normal(k[3], (2, 4, 4, 2))
+wts = jax.nn.softmax(jax.random.normal(k[4], (4, 2)), axis=-1)
+coef = jax.random.uniform(k[5], (5, 2, 4)) + 0.5
+
+def run(x, w, e, preds, xt, wts, coef):
+    return (ops.ragged_expert_matmul(x, w, e),
+            ops.fused_step(preds, xt, wts, coef, 0.1, g=2, cfg_scale=7.5))
+
+def on_mesh(*a):
+    with mesh_scope(mesh):
+        return run(*a)
+
+args = (x, w, e, preds, xt, wts, coef)
+plain = jax.jit(run)(*args)
+w_sh = jax.device_put(w, NamedSharding(mesh, P("expert")))
+meshed = jax.jit(on_mesh)(x, w_sh, *args[2:])
+for a, b in zip(plain, meshed):
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+print("mesh kernels ok")
+"""
+
+
+def test_pallas_launches_run_replicated_on_a_mesh():
+    """The TPU compiler cannot partition a Pallas launch, so on a mesh
+    every hot-path kernel runs under ``shard_map`` with replicated
+    operands — here in interpret mode on a forced 2-device CPU host,
+    with expert-sharded weights, bitwise equal to the meshless call."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+    env["REPRO_FORCE_PALLAS"] = "1"
+    proc = subprocess.run([sys.executable, "-c", _MESH_KERNELS], env=env,
+                          capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert "mesh kernels ok" in proc.stdout
+
+
 @pytest.mark.slow
 def test_multi_shard_parity_dit_two_devices():
     proc = _run_parity(["--dit", "--steps", "3"])

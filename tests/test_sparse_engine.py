@@ -10,6 +10,7 @@ plus tie-determinism of top-k selection and serving-cache behaviour.
 """
 
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -291,8 +292,7 @@ def test_topk_slots_match_weights():
                                rtol=1e-5)
 
 
-def test_serving_engine_is_retrace_free(tmp_path):
-    from repro.launch.serve import ServingEngine
+def _dit_checkpoints(tmp_path):
     from repro.models import dit as D
     from repro.models.config import dit_b2, router_b2
     from repro.training import expert_metadata, save_checkpoint
@@ -311,6 +311,13 @@ def test_serving_engine_is_retrace_free(tmp_path):
     save_checkpoint(os.path.join(tmp_path, "router.npz"),
                     D.init(rcfg, jax.random.PRNGKey(9)),
                     metadata={"num_clusters": 2})
+    return cfg, rcfg
+
+
+def test_serving_engine_is_retrace_free(tmp_path):
+    from repro.launch.serve import ServingEngine
+
+    cfg, rcfg = _dit_checkpoints(tmp_path)
     engine = ServingEngine.from_checkpoint_dir(
         str(tmp_path), dit_cfg=cfg, router_cfg=rcfg,
         sampler=SamplerConfig(num_steps=3, cfg_scale=2.0, strategy="topk"),
@@ -326,6 +333,41 @@ def test_serving_engine_is_retrace_free(tmp_path):
     assert engine.stats["traces"] == 2          # new batch size -> one more
 
 
+def _constant_bytes(stablehlo: str) -> int:
+    return sum(len(h) // 2 for h in re.findall(r'dense<"0x([0-9A-F]+)">',
+                                                stablehlo))
+
+
+@pytest.mark.parametrize("capacity", [None, 3])
+def test_compiled_samplers_take_weights_as_arguments(tmp_path, capacity):
+    """Expert and router weights reach both served programs (lockstep
+    ``generate`` and the rolling scheduler step) as jit arguments.  Closed
+    over, XLA embeds them as program constants — at dit-b2 widths
+    gigabytes inside the HLO, which the chip's compiler cannot take."""
+    from repro.launch.serve import ServingEngine
+    from repro.serving import ContinuousScheduler
+
+    cfg, rcfg = _dit_checkpoints(tmp_path)
+    engine = ServingEngine.from_checkpoint_dir(
+        str(tmp_path), dit_cfg=cfg, router_cfg=rcfg,
+        sampler=SamplerConfig(num_steps=3, cfg_scale=2.0, strategy="topk"),
+        capacity=capacity,
+    )
+    weight_bytes = engine.param_store.nbytes() + sum(
+        x.nbytes for x in jax.tree.leaves(engine.router_fn))
+    assert weight_bytes > 2**20
+    text = jax.random.normal(KEY, (2, cfg.text_len, cfg.text_dim))
+    noise = jnp.zeros((2,) + engine.latent_shape)
+    gen = engine._get_compiled(2, True).lower(
+        KEY, noise, text, *engine._sampler_args())
+    sched = ContinuousScheduler(engine, max_resident=2)
+    roll = sched._get_rolling_compiled(True, text.shape[1:]).lower(
+        noise, jnp.zeros((2,), jnp.int32), jnp.zeros((2, 2), jnp.int32),
+        jnp.zeros((2, 2)), text, *engine._sampler_args())
+    for lowered in (gen, roll):
+        assert _constant_bytes(lowered.as_text()) < 2**16
+
+
 def test_stack_and_gather_expert_params():
     from repro.models import dit as D
 
@@ -338,3 +380,18 @@ def test_stack_and_gather_expert_params():
     np.testing.assert_allclose(np.asarray(per_sample["b"]["v"][1]), 0.0)
     one = D.gather_expert_params(stacked, jnp.asarray(1))
     np.testing.assert_allclose(np.asarray(one["w"]), 1.0)
+
+
+@pytest.mark.parametrize("reduced", [True, False])
+def test_serve_cli_configs_published_or_reduced(reduced):
+    """``--no-reduced`` serves dit-b2 at its published widths (latents
+    from the config, 32), ``--reduced`` the smoke preset."""
+    from repro.launch.serve import serve_configs
+    from repro.models.config import dit_b2
+
+    dit_cfg, rcfg = serve_configs(reduced, 8)
+    if reduced:
+        assert (dit_cfg.latent_size, dit_cfg.num_layers) == (8, 2)
+    else:
+        assert dit_cfg == dit_b2() and dit_cfg.latent_size == 32
+    assert rcfg.latent_size == dit_cfg.latent_size
