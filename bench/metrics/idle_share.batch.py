@@ -1,0 +1,7 @@
+"""Percent of the traced window in which no operation runs on the device."""
+
+
+def read(run):
+    if run.summary is None or run.summary["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - run.summary["busy_s"] / run.summary["window_s"])
