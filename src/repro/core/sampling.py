@@ -473,14 +473,15 @@ def _sample_fused(
         return plan
 
     def routed_plan(x, tb):
-        w = fusion_weights(
-            experts, router_fn, x, tb,
-            strategy=config.strategy, top_k=config.top_k,
-            threshold=config.threshold,
-            ddpm_low_noise_only=config.ddpm_low_noise_only,
-            valid=valid, cluster_map=cluster_map,
-        )                                                 # (B, K)
-        return make_plan(w)
+        with jax.named_scope("router"):
+            w = fusion_weights(
+                experts, router_fn, x, tb,
+                strategy=config.strategy, top_k=config.top_k,
+                threshold=config.threshold,
+                ddpm_low_noise_only=config.ddpm_low_noise_only,
+                valid=valid, cluster_map=cluster_map,
+            )                                             # (B, K)
+            return make_plan(w)
 
     def velocity_update(plan, x, tb, dt, tab):
         # Unfused three-op chain: fused velocity, CFG combine, Euler —
@@ -913,18 +914,19 @@ def sample_ensemble_step(
         )                                                 # (B, K)
         return routed_slots(w, k_slots, valid=valid)
 
-    new_idx, new_w = jax.lax.cond(
-        jnp.any(refresh), fresh_slots, lambda: (slot_idx, slot_w)
-    )
-    slot_idx = jnp.where(refresh[:, None], new_idx, slot_idx)
-    slot_w = jnp.where(refresh[:, None], new_w, slot_w)
-
-    plan = plan_from_slots(slot_idx, slot_w, num_slots)
-    if plan_sharding is not None:
-        plan = jax.tree.map(
-            lambda a: jax.lax.with_sharding_constraint(a, plan_sharding),
-            plan,
+    with jax.named_scope("router"):
+        new_idx, new_w = jax.lax.cond(
+            jnp.any(refresh), fresh_slots, lambda: (slot_idx, slot_w)
         )
+        slot_idx = jnp.where(refresh[:, None], new_idx, slot_idx)
+        slot_w = jnp.where(refresh[:, None], new_w, slot_w)
+
+        plan = plan_from_slots(slot_idx, slot_w, num_slots)
+        if plan_sharding is not None:
+            plan = jax.tree.map(
+                lambda a: jax.lax.with_sharding_constraint(a, plan_sharding),
+                plan,
+            )
 
     # CFG orchestration mirrors _sample_fused.fused_step_update; the
     # `tab` executors receive is unused by `predictions` (only the

@@ -193,36 +193,37 @@ def fused_step(
     entries equal the scalar is bitwise identical to the scalar form on
     both dispatch paths.
     """
-    k = preds.shape[0]
-    b = x_t.shape[0]
-    latent_shape = x_t.shape[1:]
-    tsize = 1
-    for s in latent_shape:
-        tsize *= s
-    pf = preds.reshape(k, g, b, tsize)
-    xf = x_t.reshape(b, tsize)
-    wf = weights.reshape(g, b, k)
-    cf = coef.reshape(5, k, g, b)
-    dt = jnp.asarray(dt, jnp.float32).reshape(-1)
-    assert dt.shape[0] in (1, b), dt.shape
-    if use_pallas():
-        t = tsize
-        tp, block = _tile_pad(t)
-        if tp != t:
-            pad = ((0, 0), (0, 0), (0, 0), (0, tp - t))
-            pf = jnp.pad(pf, pad)
-            xf = jnp.pad(xf, ((0, 0), (0, tp - t)))
-        out = _launch(functools.partial(
-            _hetero_fuse_step,
-            cfg_scale=cfg_scale, clamp=clamp, alpha_min=alpha_min,
-            block_t=block, interpret=_interpret(),
-        ), pf, xf, wf, cf, dt)[:, :t]
-    else:
-        out = _ref.ref_hetero_fuse_step(
-            pf, xf, wf, cf, dt,
-            cfg_scale=cfg_scale, clamp=clamp, alpha_min=alpha_min,
-        )
-    return out.reshape((b,) + latent_shape)
+    with jax.named_scope("fused_step"):
+        k = preds.shape[0]
+        b = x_t.shape[0]
+        latent_shape = x_t.shape[1:]
+        tsize = 1
+        for s in latent_shape:
+            tsize *= s
+        pf = preds.reshape(k, g, b, tsize)
+        xf = x_t.reshape(b, tsize)
+        wf = weights.reshape(g, b, k)
+        cf = coef.reshape(5, k, g, b)
+        dt = jnp.asarray(dt, jnp.float32).reshape(-1)
+        assert dt.shape[0] in (1, b), dt.shape
+        if use_pallas():
+            t = tsize
+            tp, block = _tile_pad(t)
+            if tp != t:
+                pad = ((0, 0), (0, 0), (0, 0), (0, tp - t))
+                pf = jnp.pad(pf, pad)
+                xf = jnp.pad(xf, ((0, 0), (0, tp - t)))
+            out = _launch(functools.partial(
+                _hetero_fuse_step,
+                cfg_scale=cfg_scale, clamp=clamp, alpha_min=alpha_min,
+                block_t=block, interpret=_interpret(),
+            ), pf, xf, wf, cf, dt)[:, :t]
+        else:
+            out = _ref.ref_hetero_fuse_step(
+                pf, xf, wf, cf, dt,
+                cfg_scale=cfg_scale, clamp=clamp, alpha_min=alpha_min,
+            )
+        return out.reshape((b,) + latent_shape)
 
 
 def dequant_params(
@@ -375,7 +376,10 @@ def ragged_expert_matmul(
     if use_pallas() and tiles is not None:
         bm, fp, bf = tiles
         xf = x.reshape(p * m, d)
-        wp = jnp.pad(w, ((0, 0), (0, 0), (0, fp - f))) if fp != f else w
+        wp = w
+        if fp != f:
+            with jax.named_scope("layer_weights"):
+                wp = jnp.pad(w, ((0, 0), (0, 0), (0, fp - f)))
         tile_e = jnp.repeat(expert_ids, m // bm)
         if quantized:
             x32 = xf.astype(jnp.float32)

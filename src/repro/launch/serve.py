@@ -102,6 +102,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import (
@@ -1176,19 +1177,21 @@ class ServingEngine:
     def generate(
         self, key, batch_text_emb: jnp.ndarray | None, batch_size: int,
     ) -> jnp.ndarray:
-        self.stats["requests"] += 1
-        has_text = batch_text_emb is not None
-        fn = self._get_compiled(batch_size, has_text)
-        noise = jax.random.normal(
-            key, (batch_size,) + self.latent_shape, dtype=jnp.float32
-        )
-        if has_text:
-            batch_text_emb = self._cached_cond(batch_text_emb)
-        else:
-            batch_text_emb = jnp.zeros((0,), jnp.float32)   # static filler
-        self._count_plan_refreshes()
-        self._count_routed_rows(batch_size, has_text)
-        return self._run_compiled(fn, key, noise, batch_text_emb)
+        with TraceAnnotation("engine.prepare"):
+            self.stats["requests"] += 1
+            has_text = batch_text_emb is not None
+            fn = self._get_compiled(batch_size, has_text)
+            noise = jax.random.normal(
+                key, (batch_size,) + self.latent_shape, dtype=jnp.float32
+            )
+            if has_text:
+                batch_text_emb = self._cached_cond(batch_text_emb)
+            else:                                       # static filler
+                batch_text_emb = jnp.zeros((0,), jnp.float32)
+            self._count_plan_refreshes()
+            self._count_routed_rows(batch_size, has_text)
+        with TraceAnnotation("engine.dispatch"):
+            return self._run_compiled(fn, key, noise, batch_text_emb)
 
     # -- cross-request batching queue ---------------------------------------
 

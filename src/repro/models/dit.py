@@ -205,10 +205,11 @@ def _self_attn(cfg: DiTConfig, p, x: Array) -> Array:
     b, s, _ = x.shape
     q, k, v = L.gqa_project(p, x, cfg.num_heads, cfg.num_heads, hd)
     pos = jnp.arange(s)
-    out = L.chunked_attention(
-        q, k, v, q_positions=pos, kv_positions=pos, causal=False,
-        chunk_size=cfg.attn_chunk,
-    )
+    with jax.named_scope("attention"):
+        out = L.chunked_attention(
+            q, k, v, q_positions=pos, kv_positions=pos, causal=False,
+            chunk_size=cfg.attn_chunk,
+        )
     return L.dense(p["wo"], out.reshape(b, s, d))
 
 
@@ -220,10 +221,11 @@ def _cross_attn(cfg: DiTConfig, p, x: Array, text: Array) -> Array:
     q = L.dense(p["wq"], x).reshape(b, s, cfg.num_heads, hd)
     k = L.dense(p["wk"], text).reshape(b, m, cfg.num_heads, hd)
     v = L.dense(p["wv"], text).reshape(b, m, cfg.num_heads, hd)
-    out = L.chunked_attention(
-        q, k, v, q_positions=jnp.arange(s), kv_positions=jnp.arange(m),
-        causal=False, chunk_size=cfg.attn_chunk,
-    )
+    with jax.named_scope("attention"):
+        out = L.chunked_attention(
+            q, k, v, q_positions=jnp.arange(s), kv_positions=jnp.arange(m),
+            causal=False, chunk_size=cfg.attn_chunk,
+        )
     return L.dense(p["wo"], out.reshape(b, s, d))
 
 
@@ -398,7 +400,8 @@ def _layer_view(tree, layer: int):
             return QuantLeaf(a.q[:, layer], a.scale, a.compute_dtype)
         return a[:, layer]
 
-    return jax.tree.map(f, tree)
+    with jax.named_scope("layer_weights"):
+        return jax.tree.map(f, tree)
 
 
 def make_ragged_expert_apply(cfg: DiTConfig):
@@ -495,10 +498,11 @@ def make_ragged_expert_apply(cfg: DiTConfig):
             v = pd(bp["attn"]["wv"], hn).reshape(
                 -1, t_tok, cfg.num_heads, hd)
             pos = jnp.arange(t_tok)
-            att = L.chunked_attention(
-                q, k, v, q_positions=pos, kv_positions=pos, causal=False,
-                chunk_size=cfg.attn_chunk,
-            )
+            with jax.named_scope("attention"):
+                att = L.chunked_attention(
+                    q, k, v, q_positions=pos, kv_positions=pos,
+                    causal=False, chunk_size=cfg.attn_chunk,
+                )
             att = pd(bp["attn"]["wo"], att.reshape(h.shape))
             return h + a_msa[ex + (None,)] * att
 
@@ -541,11 +545,12 @@ def make_ragged_expert_apply(cfg: DiTConfig):
                     -1, t_txt, cfg.num_heads, hd)
                 v = pd(cp["wv"], text).reshape(
                     -1, t_txt, cfg.num_heads, hd)
-                att = L.chunked_attention(
-                    q, k, v, q_positions=jnp.arange(t_tok),
-                    kv_positions=jnp.arange(t_txt), causal=False,
-                    chunk_size=cfg.attn_chunk,
-                )
+                with jax.named_scope("attention"):
+                    att = L.chunked_attention(
+                        q, k, v, q_positions=jnp.arange(t_tok),
+                        kv_positions=jnp.arange(t_txt), causal=False,
+                        chunk_size=cfg.attn_chunk,
+                    )
                 h = h + pd(cp["wo"], att.reshape(h.shape))
             hn = L.layernorm({}, h) * (1.0 + g_mlp[:, None, None]) \
                 + b_mlp[:, None, None]                     # Eq. 19

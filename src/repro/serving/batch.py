@@ -27,6 +27,7 @@ same key would return (proven in ``tests/test_continuous.py``).
 
 from __future__ import annotations
 
+import collections
 import functools
 
 import jax
@@ -130,6 +131,10 @@ class RollingBatch:
         #: requests oldest-first so re-queues preserve seq order.
         self._order: list[int] = []
         self._by_seq: dict[int, object] = {}
+        #: ``t_idx`` outputs of dispatched ticks the scheduler has not yet
+        #: seen finish on the device, oldest first (never donated, so
+        #: holding them keeps no latent alive).
+        self.unfinished: collections.deque = collections.deque()
 
     # -- occupancy ----------------------------------------------------------
 

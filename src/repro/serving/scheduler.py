@@ -36,9 +36,16 @@ Layering:
   epoch simply opens a new bucket while the old one drains).
 * **observability** — ``metrics`` (``repro.serving.metrics``) records
   queue-wait and end-to-end latency per request in seconds and scheduler
-  steps; each tick folds the percentile snapshot into
-  ``engine.stats`` (``latency_p50_s`` …) and :meth:`line` renders the
-  one-line summary the serve CLI prints.
+  steps; a tick that resolves a request folds the percentile snapshot
+  into ``engine.stats`` (``latency_p50_s`` …) and :meth:`line` renders
+  the one-line summary the serve CLI prints.  Before each compiled tick
+  the scheduler counts the earlier ticks the device has not finished
+  (``engine.stats["ticks_in_flight"]``, history in ``in_flight``).  Each
+  phase of a tick runs in a profiler span (``sched.admit``,
+  ``sched.advance``, ``sched.collect``, ``sched.publish``) and each
+  request's submit, admission and resolution in one carrying its ``seq``
+  (``request.submit``, ``request.admit``, ``request.resolve``); with
+  the profiler off a span costs about a microsecond of host time.
 * **resilience hooks** — three overridable no-op seams
   (:meth:`_admission_blocked`, :meth:`_on_admit`,
   :meth:`_accept_result`) let ``repro.serving.resilience``'s
@@ -55,11 +62,13 @@ independence — see ``sample_ensemble_step``), proven in
 
 from __future__ import annotations
 
+import collections
 import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import sample_ensemble_step
@@ -74,6 +83,10 @@ class AdmissionError(RuntimeError):
 
 class QueueBackpressure(AdmissionError):
     """Queue depth hit ``max_queue_depth`` — shed load and retry later."""
+
+
+#: readings of the ticks-in-flight counter the scheduler keeps
+IN_FLIGHT_RING = 1024
 
 
 class ContinuousScheduler:
@@ -138,6 +151,10 @@ class ContinuousScheduler:
         self.steps_per_tick = steps_per_tick
         self.clock = clock
         self.metrics = LatencyRecorder()
+        #: ticks dispatched and not finished on the device, read before
+        #: each compiled tick (newest last)
+        self.in_flight: collections.deque[int] = collections.deque(
+            maxlen=IN_FLIGHT_RING)
         self.step_count = 0
         K = len(engine.experts)
         self.k_slots = 1 if cfg.strategy == "top1" else min(cfg.top_k, K)
@@ -177,16 +194,18 @@ class ContinuousScheduler:
                 f"scheduler queue is full ({self.max_queue_depth} "
                 f"requests waiting); retry after step() drains it"
             )
-        req = PendingRequest(
-            key=key, text_emb=eng._cached_cond(text_emb),
-            batch_size=batch_size, _membership=eng._membership(),
-        )
-        req.seq = eng._next_seq()
-        self._timings[req.seq] = RequestTiming(
-            submit_t=self.clock(), submit_step=self.step_count
-        )
-        self._queue.append(req)
-        eng.stats["requests"] += 1
+        with TraceAnnotation("request.submit") as span:
+            req = PendingRequest(
+                key=key, text_emb=eng._cached_cond(text_emb),
+                batch_size=batch_size, _membership=eng._membership(),
+            )
+            req.seq = eng._next_seq()
+            span.set_metadata(seq=req.seq)
+            self._timings[req.seq] = RequestTiming(
+                submit_t=self.clock(), submit_step=self.step_count
+            )
+            self._queue.append(req)
+            eng.stats["requests"] += 1
         return req
 
     # -- scheduling tick ----------------------------------------------------
@@ -195,7 +214,8 @@ class ContinuousScheduler:
         """One scheduler tick: admit → advance every bucket one Euler
         step → resolve finished requests.  Returns the number resolved."""
         self.step_count += 1
-        self._admit()
+        with TraceAnnotation("sched.admit"):
+            self._admit()
         for sig, bucket in list(self._buckets.items()):
             if bucket.num_resident == 0:
                 continue
@@ -203,9 +223,12 @@ class ContinuousScheduler:
                 self._advance(bucket)
             except Exception as e:          # noqa: BLE001 — isolate bucket
                 self._fail_bucket(sig, bucket, e)
-        resolved = self._collect()
+        with TraceAnnotation("sched.collect"):
+            resolved = self._collect()
         self._gc_buckets()
-        self.engine.stats.update(self.metrics.snapshot())
+        if resolved:                # the snapshot moves only on resolution
+            with TraceAnnotation("sched.publish"):
+                self.engine.stats.update(self.metrics.snapshot())
         self.engine.stats["scheduler_steps"] = self.step_count
         return resolved
 
@@ -260,6 +283,7 @@ class ContinuousScheduler:
             f"scheduler: step={self.step_count} "
             f"resident={self.num_resident}/{self.max_resident} "
             f"queued={len(self._queue)} "
+            f"inflight={self.engine.stats.get('ticks_in_flight', 0)} "
             f"done={self.metrics.completed} "
             f"({s['throughput_img_s']:.1f} img/s) "
             f"wait p50={f('queue_wait_p50_steps')} "
@@ -296,10 +320,11 @@ class ContinuousScheduler:
                 blocked.add(sig)
                 rest.append(req)
                 continue
-            noise = draw_noise(
-                req.key, (req.batch_size,) + eng.latent_shape
-            )
-            bucket.admit(req, noise)
+            with TraceAnnotation("request.admit", seq=req.seq):
+                noise = draw_noise(
+                    req.key, (req.batch_size,) + eng.latent_shape
+                )
+                bucket.admit(req, noise)
             req.state = "RESIDENT"
             tm = self._timings[req.seq]
             tm.admit_t = self.clock()
@@ -342,17 +367,36 @@ class ContinuousScheduler:
 
     def _advance(self, bucket: RollingBatch) -> None:
         eng = self.engine
-        has_text = bucket.text is not None
-        fn = self._get_rolling_compiled(has_text, bucket.text_tail)
-        text = bucket.text if has_text \
-            else jnp.zeros((0,), jnp.float32)            # static filler
-        args = eng._sampler_args(bucket.membership)
-        if eng.elastic:
-            eng._note_degraded(args[1], steps=1)
-        out = fn(bucket.x, bucket.t_idx, bucket.slot_idx, bucket.slot_w,
-                 text, *args)
+        inflight = self._count_in_flight()
+        with TraceAnnotation("sched.advance", inflight=inflight):
+            has_text = bucket.text is not None
+            fn = self._get_rolling_compiled(has_text, bucket.text_tail)
+            text = bucket.text if has_text \
+                else jnp.zeros((0,), jnp.float32)        # static filler
+            args = eng._sampler_args(bucket.membership)
+            if eng.elastic:
+                eng._note_degraded(args[1], steps=1)
+            out = fn(bucket.x, bucket.t_idx, bucket.slot_idx,
+                     bucket.slot_w, text, *args)
         bucket.x, bucket.t_idx, bucket.slot_idx, bucket.slot_w = out
+        bucket.unfinished.append(bucket.t_idx)
         bucket.advance_host(self.steps_per_tick)
+
+    def _count_in_flight(self) -> int:
+        """Ticks dispatched and not yet finished on the device, over all
+        buckets; recorded in ``in_flight`` and published as
+        ``engine.stats["ticks_in_flight"]``.  A tick's ``t_idx`` output
+        (never donated) reports ``is_ready()`` once the device has run
+        it; ticks of one bucket finish in order.  Never blocks."""
+        n = 0
+        for bucket in self._buckets.values():
+            q = bucket.unfinished
+            while q and q[0].is_ready():
+                q.popleft()
+            n += len(q)
+        self.in_flight.append(n)
+        self.engine.stats["ticks_in_flight"] = n
+        return n
 
     def _get_rolling_compiled(self, has_text: bool, text_tail):
         """Jitted rolling step, cached in the engine's compiled-fn cache
@@ -452,25 +496,30 @@ class ContinuousScheduler:
             # forces a device sync, so ticks pipeline asynchronously and
             # only result() materialization blocks.
             for req in bucket.finished_requests():
-                rows = bucket.rows_of(req.seq)
-                out = bucket.resolve(req)
-                if not self._accept_result(bucket, req, out, rows):
-                    continue
-                req._result = out
-                req.done = True
-                req.state = "DONE"
-                tm = self._timings.pop(req.seq)
-                now = self.clock()
-                self.metrics.observe(
-                    queue_wait_s=tm.admit_t - tm.submit_t,
-                    e2e_s=now - tm.submit_t,
-                    queue_wait_steps=tm.admit_step - tm.submit_step,
-                    e2e_steps=self.step_count - tm.submit_step,
-                    images=req.batch_size,
-                    now=now,
-                )
-                resolved += 1
+                with TraceAnnotation("request.resolve", seq=req.seq):
+                    resolved += self._resolve(bucket, req)
         return resolved
+
+    def _resolve(self, bucket: RollingBatch, req) -> int:
+        """Hand a finished request its latents; 1 if it resolved DONE."""
+        rows = bucket.rows_of(req.seq)
+        out = bucket.resolve(req)
+        if not self._accept_result(bucket, req, out, rows):
+            return 0
+        req._result = out
+        req.done = True
+        req.state = "DONE"
+        tm = self._timings.pop(req.seq)
+        now = self.clock()
+        self.metrics.observe(
+            queue_wait_s=tm.admit_t - tm.submit_t,
+            e2e_s=now - tm.submit_t,
+            queue_wait_steps=tm.admit_step - tm.submit_step,
+            e2e_steps=self.step_count - tm.submit_step,
+            images=req.batch_size,
+            now=now,
+        )
+        return 1
 
     def _fail_bucket(self, sig: tuple, bucket: RollingBatch, e) -> None:
         """Isolate a failing bucket: release + re-queue its residents in
