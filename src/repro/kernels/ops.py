@@ -303,19 +303,23 @@ def ragged_tiles(m: int, d: int, f: int, x_bytes: int, w_bytes: int,
                  quantized: bool) -> tuple[int, int, int] | None:
     """``(block_m, padded F, block_f)`` for a ragged GEMM, or ``None``.
 
-    Rows start from :func:`ragged_block_m`; output lanes pad by the
-    shared :func:`_tile_pad` policy, and the block is the widest
-    lane-multiple divisor of the padded width (at most ``_TILE_BLOCK``)
-    whose grid step fits ``_RAGGED_VMEM_BUDGET`` at contraction depth
-    ``d``.  When even a one-lane-tile block overflows, the row block
-    halves (staying a multiple of 8).  ``None`` — row groups that cannot
-    tile — sends the caller to the dense-math fallback.
+    Rows start from :func:`ragged_block_m`.  Output lanes pad only to
+    the next lane multiple (the lane width from :func:`_tile_pad`), not
+    to whole ``_TILE_BLOCK`` tiles: the grid tiles F by any lane-multiple
+    block, so a lane-aligned width (1152 = 9 lanes at DiT-XL/2) runs
+    unpadded, with no padded columns to multiply and no per-call pad of
+    the weight leaf.  The block is the widest lane-multiple divisor of
+    the padded width (at most ``_TILE_BLOCK``) whose grid step fits
+    ``_RAGGED_VMEM_BUDGET`` at contraction depth ``d``.  When even a
+    one-lane-tile block overflows, the row block halves (staying a
+    multiple of 8).  ``None`` — row groups that cannot tile — sends the
+    caller to the dense-math fallback.
     """
     bm = ragged_block_m(m)
     if bm is None:
         return None
-    fp, _ = _tile_pad(f)
     lane, _ = _tile_pad(1)
+    fp = -(-f // lane) * lane
     while True:
         for bf in range(min(fp, _TILE_BLOCK), 0, -lane):
             if fp % bf == 0 and _ragged_step_bytes(
@@ -345,7 +349,9 @@ def ragged_expert_matmul(
 
     On the Pallas path the groups flatten to ``(P·m, D)`` tile-aligned
     rows for :func:`repro.kernels.ragged_gemm.ragged_gemm` (tiles from
-    :func:`ragged_tiles`; output lanes pad and slice back); quantized
+    :func:`ragged_tiles`; a width that is not a lane multiple pads its
+    weight leaf and slices the output back, a lane-aligned one neither
+    pads nor slices); quantized
     weights (int8 / fp8, with ``w_scale``) keep their storage dtype all
     the way to the MXU — activations quantize per row symmetrically to
     the same storage format and the kernel fuses the
@@ -399,7 +405,9 @@ def ragged_expert_matmul(
                 _ragged_gemm, block_m=bm, block_f=bf,
                 interpret=_interpret(),
             ), xf, wp, tile_e)
-        y = y[:, :f].reshape((p,) + mids + (f,))
+        if fp != f:
+            y = y[:, :f]
+        y = y.reshape((p,) + mids + (f,))
     else:
         if quantized:
             wd = w.astype(jnp.float32) * w_scale.astype(jnp.float32).reshape(

@@ -21,7 +21,9 @@ leaf — the Megablocks-style grouped-GEMM economy, applied to the
 
 Tile geometry (``block_m`` rows × ``block_f`` output lanes, full-depth
 contraction) is decided by the ``ops.ragged_expert_matmul`` wrapper
-(``ops.ragged_tiles``: lane padding plus a VMEM budget that counts the
+(``ops.ragged_tiles``: F pads only to the next 128-lane multiple, since
+``block_f`` may be any lane-multiple divisor of the width, so a
+lane-aligned layer runs unpadded; plus a VMEM budget that counts the
 contraction depth); this module never hard-codes lane arithmetic.
 ``debug=True`` adds a per-grid-step tile counter output so tests can
 *measure* that empty segments cost zero tiles.

@@ -232,15 +232,70 @@ def test_ragged_block_m_policy():
     assert ops.ragged_block_m(0) is None
 
 
-@pytest.mark.parametrize("force_pallas", ["1", "0"])
+#: the expert denses of the two served widths, (d_in, d_out) -> today's
+#: (block_m, padded F, block_f) at 256-row groups and a float32 store.
+B2_TILES = {
+    (16, 768): (256, 768, 768),       # patch embedding
+    (768, 768): (256, 768, 768),      # attention projections
+    (768, 3072): (256, 3072, 1024),   # MLP up
+    (3072, 768): (256, 768, 128),     # MLP down (deep contraction)
+    (768, 16): (256, 128, 128),       # final.out, not a lane multiple
+}
+
+
+@pytest.mark.parametrize("d", [1152, 4608])
+@pytest.mark.parametrize("f", [1152, 4608, 3456])
+def test_ragged_tiles_lane_aligned_width_runs_unpadded(d, f):
+    """A lane-multiple output width that is not a multiple of the
+    1024-lane tile (DiT-XL/2's 1152 and 4608) is tiled by a divisor of
+    its own width instead of padding to the next tile multiple."""
+    bm, fp, bf = ops.ragged_tiles(256, d, f, 4, 4, False)
+    assert fp == f
+    assert f % bf == 0 and bf <= ops._TILE_BLOCK
+    assert ops._ragged_step_bytes(bm, d, bf, 4, 4, False) \
+        <= ops._RAGGED_VMEM_BUDGET
+
+
+@pytest.mark.parametrize("shape", sorted(B2_TILES))
+def test_ragged_tiles_b2_shapes_unchanged(shape):
+    d, f = shape
+    assert ops.ragged_tiles(256, d, f, 4, 4, False) == B2_TILES[shape]
+
+
+@pytest.mark.parametrize("f", [40, 16])
+def test_ragged_tiles_unaligned_width_pads_to_lane(f):
+    assert ops.ragged_tiles(256, 768, f, 4, 4, False)[1:] == (128, 128)
+
+
+@pytest.mark.parametrize("f,padded", [(40, True), (1152, False)])
+def test_ragged_expert_matmul_pads_weights_only_off_lane(
+    f, padded, monkeypatch
+):
+    """The Pallas path pads the weight leaf (and slices the output) only
+    for a width that is not a lane multiple."""
+    monkeypatch.setenv("REPRO_FORCE_PALLAS", "1")
+    x = _rand((4, 16, 32), seed=16)
+    w = _rand((3, 32, f), seed=17)
+    eids = jnp.array([0, 2, 1, 2], jnp.int32)
+    jaxpr = str(jax.make_jaxpr(ops.ragged_expert_matmul)(x, w, eids))
+    assert (" pad[" in jaxpr) == padded
+    assert (" slice[" in jaxpr) == padded
+
+
+@pytest.mark.parametrize("force_pallas,f", [
+    pytest.param("1", 40, id="1"),
+    pytest.param("0", 40, id="0"),
+    pytest.param("1", 1152, id="1-f1152"),
+])
 def test_ragged_expert_matmul_matches_gathered_einsum(
-    force_pallas, monkeypatch
+    force_pallas, f, monkeypatch
 ):
     """Wrapper == gathered dense einsum on both the Pallas (interpret) and
     fallback paths, with a non-tile-aligned output width (F=40 pads to the
-    _tile_pad lane multiple and slices back)."""
+    lane multiple and slices back) and a lane-aligned one that is not a
+    multiple of the 1024-lane tile (F=1152 runs unpadded, 3 lane tiles)."""
     monkeypatch.setenv("REPRO_FORCE_PALLAS", force_pallas)
-    P, m, d, f, K = 6, 16, 32, 40, 4
+    P, m, d, K = 6, 16, 32, 4
     x = _rand((P, m, d), seed=10)
     w = _rand((K, d, f), seed=11)
     b = _rand((K, f), seed=12)

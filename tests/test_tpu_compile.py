@@ -36,7 +36,7 @@ from repro.kernels.hetero_fuse import (
 )
 from repro.kernels.ragged_gemm import ragged_gemm
 from repro.launch.sharding import mesh_scope
-from repro.models.config import dit_b2
+from repro.models.config import dit_b2, dit_xl2
 
 _CFG = dit_b2()
 _D = _CFG.d_model
@@ -47,13 +47,21 @@ _K, _BATCH, _TOP_K, _G = 8, 8, 2, 2
 _GROUPS = _BATCH * _TOP_K * _G                                # 32
 _MLP = _CFG.d_ff                                              # 3072
 
-#: every expert dense that runs through the ragged GEMM: (d_in, d_out)
+_XL = dit_xl2()
+_XL_D, _XL_MLP = _XL.d_model, _XL.d_ff                        # 1152, 4608
+
+#: every expert dense that runs through the ragged GEMM: (d_in, d_out);
+#: the dit-xl2 widths are lane multiples that are not whole 1024-lane
+#: tiles, so they run unpadded with a narrower ``block_f``.
 DENSE_SHAPES = {
     "patch_embed": (_PATCH_IN, _D),
     "attn_proj": (_D, _D),
     "mlp_up": (_D, _MLP),
     "mlp_down": (_MLP, _D),
     "final_out": (_D, _PATCH_IN),
+    "xl2_attn_proj": (_XL_D, _XL_D),
+    "xl2_mlp_up": (_XL_D, _XL_MLP),
+    "xl2_mlp_down": (_XL_MLP, _XL_D),
 }
 
 
@@ -143,6 +151,15 @@ def test_ragged_tiles_bound_deep_contraction_vmem():
     bm, fp, bf = ops.ragged_tiles(_TOKENS, _MLP, _D, 4, 4, False)
     assert fp == _D and bf < _D
     assert ops._ragged_step_bytes(bm, _MLP, bf, 4, 4, False) \
+        <= ops._RAGGED_VMEM_BUDGET
+
+
+def test_ragged_tiles_bound_xl2_deep_contraction_vmem():
+    """The dit-xl2 MLP down-projection contracts 4608 deep into 1152
+    lanes: unpadded, it halves the row block and takes one-lane tiles."""
+    bm, fp, bf = ops.ragged_tiles(_TOKENS, _XL_MLP, _XL_D, 4, 4, False)
+    assert (bm, fp, bf) == (128, _XL_D, 128)
+    assert ops._ragged_step_bytes(bm, _XL_MLP, bf, 4, 4, False) \
         <= ops._RAGGED_VMEM_BUDGET
 
 
