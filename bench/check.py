@@ -4,7 +4,8 @@ After the window has closed and the program's state is freed, a sample of
 what the window served, drawn from the seed, is run again through the
 plain reference (``reference.py``, float32 at ``highest``) from each
 request's own noise key and prompt, with weights that ``weights.py``
-makes anew from the seed.  The number compared is
+makes anew from the seed, in blocks of the configuration's
+``expert_shards``, one block a device.  The number compared is
 
     latent_gap = max over sampled images of max|served - ref| / RMS(ref)
 
@@ -52,34 +53,47 @@ def sample(cfg: dict, mix: dict, seed: int, pool: list):
 
 
 def reference_latents(cfg: dict, seed: int, keys, texts,
-                      precision: str = "highest") -> np.ndarray:
-    """The reference's final latents for the sampled requests."""
+                      precision: str = "highest", devices=None) -> np.ndarray:
+    """The reference's final latents for the sampled requests.
+
+    With ``expert_shards`` N > 1 in ``cfg`` the experts are drawn as N
+    blocks, block ``j`` on ``devices[j]`` (default: the first N devices),
+    and the router on ``devices[0]``.
+    """
     import jax
     import jax.numpy as jnp
 
+    shards = cfg.get("expert_shards", 1)
+    if shards == 1:
+        devices = None
+    elif devices is None:
+        devices = jax.devices()[:shards]
     shape = (cfg["latent_size"], cfg["latent_size"], cfg["latent_channels"])
     noise = jnp.concatenate([
         jax.random.normal(jnp.asarray(k), (t.shape[0],) + shape, jnp.float32)
         for k, t in zip(keys, texts)])
     text = jnp.asarray(np.concatenate(texts))
     m = {k: cfg[k] for k in reference.MODEL_KEYS}
-    stack = weights.expert_stack(seed, m, len(cfg["experts"]))
-    router = weights.router(seed, cfg["router"])
-    out = reference.sample(noise, text, stack, router,
-                           reference.time_grid(cfg["sampler"]["num_steps"]),
-                           spec=reference.freeze(cfg), precision=precision)
+    blocks = weights.expert_blocks(seed, m, len(cfg["experts"]), devices)
+    router = weights.router(seed, cfg["router"],
+                            None if devices is None else devices[0])
+    out = reference.sample_blocked(
+        noise, text, blocks, router,
+        reference.time_grid(cfg["sampler"]["num_steps"]),
+        spec=reference.freeze(cfg), precision=precision)
     return np.asarray(out)
 
 
-def check(cfg: dict, mix: dict, seed: int, pool: list) -> dict:
-    """``{"correct": bool, "numbers": {name: {"value", "limit"}}}``."""
+def check(cfg: dict, mix: dict, seed: int, pool: list, devices=None) -> dict:
+    """``{"correct": bool, "numbers": {name: {"value", "limit"}}}``;
+    ``devices`` as ``reference_latents`` takes them."""
     limit = cfg["check"]["latent_gap_limit"]
     keys, texts, served = sample(cfg, mix, seed, pool)
     if served is None:
         return {"correct": False, "numbers": {
             "latent_gap": {"value": None, "limit": limit},
             "nonfinite": {"value": None, "limit": 0}}}
-    ref = reference_latents(cfg, seed, keys, texts)
+    ref = reference_latents(cfg, seed, keys, texts, devices=devices)
     g = gap(served, ref)
     bad = int(np.size(served) - np.count_nonzero(np.isfinite(served)))
     return {

@@ -7,7 +7,10 @@
   nothing to read (the metric is then left out of the result line).
 
 So a later change adds a cell, a mix or a metric by adding files and
-entries, and edits nothing that is here.
+entries, and edits nothing that is here.  A configuration larger than one
+chip states ``expert_shards`` N (absent: 1), the number of chips its
+experts are placed on, K/N to a chip; the ``chips`` of its cells must
+equal N, and N must divide the number of experts.
 """
 
 from __future__ import annotations
@@ -67,6 +70,14 @@ def cell(root: str, workload: str) -> dict:
     conf = next(c for c in bench["configs"] if c["name"] == entry["config"])
     with open(os.path.join(root, conf["file"])) as f:
         config = json.load(f)
+    shards = config.get("expert_shards", 1)
+    if not isinstance(shards, int) or shards < 1 \
+            or len(config["experts"]) % shards:
+        raise ValueError(f"{conf['name']}: expert_shards {shards!r} does not "
+                         f"divide its {len(config['experts'])} experts")
+    if entry["chips"] != shards:
+        raise ValueError(f"{workload}: chips {entry['chips']} but "
+                         f"{conf['name']} places its experts on {shards}")
     mix = traffic_mod.load(traffics(root)[entry["traffic"]])
 
     def mine(metric):

@@ -23,6 +23,12 @@ Every matrix product runs at the ``precision`` given, so the same code is
 the reference (``highest``) and the control (one step lower).  Each
 routed pair gathers its expert's weights one layer at a time, so the
 whole ensemble is never copied per pair.
+
+An ensemble larger than one chip is held in blocks of experts, one block
+a device (``sample_blocked``): each block computes the pairs routed to
+its experts, a share of ``u`` in step 5 that is exactly zero for the
+others, and the shares are summed in block order before CFG.  With one
+block that is ``sample``.
 """
 
 from __future__ import annotations
@@ -32,6 +38,8 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 #: matrix-product precisions: float32 (``highest``), three bfloat16
 #: passes (``high``: hi*hi + hi*lo + lo*hi of each operand's bfloat16
@@ -229,41 +237,135 @@ def _coefficients(objectives, t):
     return jnp.stack([ddpm if o == "ddpm" else fm for o in objectives])
 
 
+def _route(cfg, router, x, t, prec):
+    """Top-k routing of latents ``x`` at time ``t``: the renormalised
+    weights (B, k) and the experts (B, k)."""
+    probs = router_probs(dict(cfg["router"]), router, x,
+                         jnp.full((x.shape[0],), t), prec)
+    vals, idx = jax.lax.top_k(probs, cfg["top_k"])
+    return vals / jnp.sum(vals, axis=-1, keepdims=True), idx
+
+
+def _share(cfg, stack, first, w, idx, x, t, text, prec):
+    """The fused velocity (B, 2, H, W, C), both guidance branches, of the
+    routed pairs whose expert lies in ``stack``: experts ``first`` up to
+    ``first`` + its size.  A pair whose expert lies elsewhere adds exactly
+    zero; a stack of every expert takes every pair."""
+    m = dict(cfg["model"])
+    b, k = idx.shape
+    e = idx.reshape(-1)
+    n = jax.tree.leaves(stack)[0].shape[0]
+    mine = None
+    if n < len(cfg["objectives"]):
+        mine = (e >= first) & (e < first + n)
+        local = jnp.where(mine, e - first, 0)
+    else:
+        local = e
+    xp = jnp.repeat(x, k, axis=0)
+    preds = expert_predict(m, stack, local, xp, jnp.full((b * k,), t),
+                           jnp.repeat(text, k, axis=0), prec)
+    co = _coefficients(cfg["objectives"], t)[e]          # (P, 5)
+    a, s, da, ds, vs = (co[:, j].reshape(-1, 1, 1, 1, 1) for j in range(5))
+    x0 = jnp.clip((xp[:, None] - s * preds) / jnp.maximum(a, cfg["alpha_min"]),
+                  -cfg["clamp"], cfg["clamp"])
+    v = (da * x0 + ds * preds) * vs                       # (P, 2, H, W, C)
+    v = v.reshape((b, k) + v.shape[1:])
+    wv = w.reshape(b, k, 1, 1, 1, 1) * v
+    if mine is not None:
+        wv = jnp.where(mine.reshape(b, k, 1, 1, 1, 1), wv, 0.0)
+    return jnp.sum(wv, axis=1)
+
+
+def _euler(cfg, x, u, t_hi, t_lo):
+    """CFG over the fused velocity ``u`` and one Euler step."""
+    u = u[:, 1] + cfg["cfg_scale"] * (u[:, 0] - u[:, 1])
+    return x - u * (t_hi - t_lo)
+
+
 @functools.partial(jax.jit, static_argnames=("spec", "precision"))
 def sample(noise, text, stack, router, grid, *, spec, precision):
     """Final latents (B, H, W, C) from ``noise`` and prompts ``text``
     (B, 77, Dt).  ``spec`` is the hashable ``freeze(config)``; ``grid``
     the (S + 1,) time grid; ``precision`` one of ``PRECISIONS``."""
     cfg = dict(spec)
-    m, r = dict(cfg["model"]), dict(cfg["router"])
-    objectives = cfg["objectives"]
-    k, cfg_scale = cfg["top_k"], cfg["cfg_scale"]
-    alpha_min, clamp = cfg["alpha_min"], cfg["clamp"]
-    prec = precision
-    b = noise.shape[0]
 
     def step(x, i):
         t_hi, t_lo = grid[i], grid[i + 1]
-        tb = jnp.full((b,), t_hi)
-        probs = router_probs(r, router, x, tb, prec)
-        vals, idx = jax.lax.top_k(probs, k)               # (B, k)
-        w = vals / jnp.sum(vals, axis=-1, keepdims=True)
-        e = idx.reshape(-1)
-        xp = jnp.repeat(x, k, axis=0)
-        preds = expert_predict(m, stack, e, xp, jnp.repeat(tb, k),
-                               jnp.repeat(text, k, axis=0), prec)
-        co = _coefficients(objectives, t_hi)[e]           # (P, 5)
-        a, s, da, ds, vs = (co[:, j].reshape(-1, 1, 1, 1, 1) for j in range(5))
-        x0 = jnp.clip((xp[:, None] - s * preds) / jnp.maximum(a, alpha_min),
-                      -clamp, clamp)
-        v = (da * x0 + ds * preds) * vs                   # (P, 2, H, W, C)
-        v = v.reshape((b, k) + v.shape[1:])
-        u = jnp.sum(w.reshape(b, k, 1, 1, 1, 1) * v, axis=1)
-        u = u[:, 1] + cfg_scale * (u[:, 0] - u[:, 1])
-        return x - u * (t_hi - t_lo), None
+        w, idx = _route(cfg, router, x, t_hi, precision)
+        u = _share(cfg, stack, 0, w, idx, x, t_hi, text, precision)
+        return _euler(cfg, x, u, t_hi, t_lo), None
 
     x, _ = jax.lax.scan(step, noise, jnp.arange(grid.shape[0] - 1))
     return x
+
+
+def sample_blocked(noise, text, blocks, router, grid, *, spec, precision):
+    """``sample`` over experts held in blocks, one block a device.
+
+    ``blocks`` are stacks of consecutive experts of equal size, block
+    ``j`` wholly on one device; the router on another, or on one of
+    theirs.  Each Euler step routes on the router's device, computes every
+    block's share of the fused velocity on the block's own device (all at
+    once, one program over the blocks' devices), sums the shares on the
+    router's device in block order, and applies CFG and the Euler step
+    there.  With one block it is ``sample`` itself.
+    """
+    if len(blocks) == 1:
+        return sample(noise, text, blocks[0], router, grid, spec=spec,
+                      precision=precision)
+    home = _device(router)
+    mesh = jax.sharding.Mesh(np.array([_device(b) for b in blocks]),
+                             ("block",))
+    whole = NamedSharding(mesh, P())
+
+    def glue(*leaves):
+        shape = (sum(a.shape[0] for a in leaves),) + leaves[0].shape[1:]
+        return jax.make_array_from_single_device_arrays(
+            shape, NamedSharding(mesh, P("block")), list(leaves))
+
+    stack = jax.tree.map(glue, *blocks)
+    text = jax.device_put(text, whole)
+    x = jax.device_put(noise, home)
+    ts = np.asarray(grid)
+    for i in range(ts.shape[0] - 1):
+        t_hi, t_lo = ts[i], ts[i + 1]
+        w, idx = _route_on(router, x, t_hi, spec=spec, precision=precision)
+        shares = _shares(stack, *jax.device_put((w, idx, x), whole), t_hi,
+                         text, spec=spec, precision=precision, mesh=mesh)
+        x = _euler_on(x, jax.device_put(shares, home), t_hi, t_lo, spec=spec)
+    return x
+
+
+def _device(tree):
+    (device,) = jax.tree.leaves(tree)[0].devices()
+    return device
+
+
+@functools.partial(jax.jit, static_argnames=("spec", "precision"))
+def _route_on(router, x, t, *, spec, precision):
+    return _route(dict(spec), router, x, t, precision)
+
+
+@functools.partial(jax.jit, static_argnames=("spec", "precision", "mesh"))
+def _shares(stack, w, idx, x, t, text, *, spec, precision, mesh):
+    """Every block's share (blocks, B, 2, H, W, C), each on its device."""
+    cfg = dict(spec)
+
+    def one(stack, w, idx, x, t, text):
+        n = jax.tree.leaves(stack)[0].shape[0]
+        first = jax.lax.axis_index("block") * n
+        return _share(cfg, stack, first, w, idx, x, t, text, precision)[None]
+
+    return jax.shard_map(one, mesh=mesh, in_specs=(P("block"),) + (P(),) * 5,
+                         out_specs=P("block"))(stack, w, idx, x, t, text)
+
+
+@functools.partial(jax.jit, static_argnames=("spec",))
+def _euler_on(x, shares, t_hi, t_lo, *, spec):
+    u = shares[0]
+    for j in range(1, shares.shape[0]):
+        u = u + shares[j]
+    return _euler(dict(spec), x, u, t_hi, t_lo)
 
 
 def time_grid(num_steps: int) -> jax.Array:

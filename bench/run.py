@@ -267,8 +267,8 @@ def run(root: str, workload: str, seed: int, seconds: float, trace: bool,
     log(f"setup_s {setup_s:.3f}; engine traces before/after the window "
         f"{got['traces'][0]}/{got['traces'][1]}; XLA compiles in the window "
         f"{counter.count}")
-    stats = dev.memory_stats() or {}
-    peak_bytes = stats.get("peak_bytes_in_use", 0)
+    peak_each = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+                 for d in devices[:cell["workload"]["chips"]]]
     del engine
     gc.collect()
 
@@ -280,7 +280,9 @@ def run(root: str, workload: str, seed: int, seconds: float, trace: bool,
     else:
         attempted, failed = got["calls"], 0
         pool = list(enumerate(got["outputs"]))
-    checks = check.check(cfg, mix, seed, pool)
+    t_check = time.perf_counter()
+    checks = check.check(cfg, mix, seed, pool, system.expert_devices(cfg))
+    log(f"check_s {time.perf_counter() - t_check:.3f}")
 
     result = {
         "correct": checks["correct"],
@@ -288,7 +290,8 @@ def run(root: str, workload: str, seed: int, seconds: float, trace: bool,
         "failed": failed,
         "metrics": {},
         "device": {"platform": dev.platform, "kind": dev.device_kind,
-                   "count": len(devices), "memory_peak_bytes": peak_bytes},
+                   "count": len(devices), "memory_peak_bytes": max(peak_each),
+                   "memory_peak_bytes_per_device": peak_each},
     }
     if trace:
         from bench import trace as trace_mod

@@ -32,9 +32,23 @@ def dit_configs(cfg: dict):
     return expert, router
 
 
+def expert_devices(cfg: dict) -> list | None:
+    """The device of each expert shard of ``cfg``, in the order of the
+    program's own expert mesh (``jax.make_mesh`` may order devices by
+    topology); None for a configuration on one chip."""
+    from repro.launch.mesh import make_expert_mesh
+
+    shards = cfg.get("expert_shards", 1)
+    if shards == 1:
+        return None
+    return list(make_expert_mesh(shards, 1).devices[:, 0])
+
+
 def build_engine(cfg: dict, seed: int):
     """``ServingEngine`` over ``cfg``'s experts and router, with weights
-    drawn on the device from ``seed``."""
+    drawn on the device from ``seed``.  A configuration with
+    ``expert_shards`` N > 1 gets an (expert N, data 1) mesh, and each
+    expert is drawn on the device of its shard."""
     from repro.core import ExpertSpec, SamplerConfig
     from repro.launch.serve import ServingEngine
     from repro.models import dit as D
@@ -48,14 +62,16 @@ def build_engine(cfg: dict, seed: int):
                    ragged_apply_fn=ragged_fn)
         for i, x in enumerate(cfg["experts"])
     ]
-    n = len(specs)
-    params = weights.expert_list(seed, model_sizes(cfg), n)
+    devices = expert_devices(cfg)
+    params = weights.expert_list(seed, model_sizes(cfg), len(specs), devices)
+    layout = {} if devices is None else {
+        "n_expert_shards": len(devices), "n_data_shards": 1}
     router_fn = D.make_router_fn(rcfg, weights.router(seed, cfg["router"]))
     engine = ServingEngine(
         experts=specs, expert_params=params, router_fn=router_fn,
         latent_shape=(ecfg.latent_size, ecfg.latent_size,
                       ecfg.latent_channels),
-        sampler=SamplerConfig(**cfg["sampler"]),
+        sampler=SamplerConfig(**cfg["sampler"]), **layout,
     )
     del params                  # the engine holds the weights from here on
     return engine

@@ -38,16 +38,17 @@ def _unchanged(monkeypatch):
 
 
 def _half_batch(monkeypatch):
-    """Half of the rows are left out of the step: the first half, where
-    the rolling batch places requests first."""
+    """Half of the work is left out of the step: the first half of every
+    row's channels keeps its value, so each served request is hit, in
+    whichever row the rolling batch placed it."""
     from repro.kernels import ops
 
     real = ops.fused_step
 
     def half(preds, x_t, *a, **k):
         out = real(preds, x_t, *a, **k)
-        h = max(x_t.shape[0] // 2, 1)
-        return jnp.concatenate([x_t[:h], out[h:]])
+        h = max(x_t.shape[-1] // 2, 1)
+        return jnp.concatenate([x_t[..., :h], out[..., h:]], axis=-1)
 
     monkeypatch.setattr(ops, "fused_step", half)
 
@@ -78,15 +79,15 @@ def _wrong_rows(monkeypatch):
     monkeypatch.setattr(batch.RollingBatch, "resolve", resolve)
 
 
-def _control(monkeypatch):
-    """The control: the plain reference one precision step lower
-    (``high``, for float32 at ``highest``) put in the served program's
-    place, answering each call and each request from its own key and
-    prompt."""
+def _control(monkeypatch, cfg=None):
+    """The control: the plain reference of ``cfg`` (default the tiny
+    configuration) one precision step lower (``high``, for float32 at
+    ``highest``) put in the served program's place, answering each call
+    and each request from its own key and prompt."""
     from repro.launch.serve import ServingEngine
     from repro.serving.batch import RollingBatch
 
-    cfg = tiny()
+    cfg = cfg or tiny()
     res = RollingBatch.resolve
 
     def control(key, text):

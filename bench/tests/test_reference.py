@@ -32,10 +32,29 @@ def test_weights_list_and_stack_agree():
     cfg = tiny()
     m = system.model_sizes(cfg)
     lst = weights.expert_list(SEED, m, 8)
-    stack = weights.expert_stack(SEED, m, 8)
+    (stack,) = weights.expert_blocks(SEED, m, 8)
     for e in (0, 5):
         for a, b in zip(jax.tree.leaves(lst[e]), jax.tree.leaves(stack)):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b)[e])
+
+
+def test_one_block_is_the_unblocked_reference(served):
+    """On one chip the check hands the reference one block, the whole
+    stack: the latents are those of ``reference.sample`` over the stack
+    of the engine's own draws, bit for bit."""
+    cfg, key, text, _ = served
+    m = system.model_sizes(cfg)
+    stack = jax.tree.map(lambda *xs: np.stack(xs),
+                         *weights.expert_list(SEED, m, 8))
+    shape = (4, cfg["latent_size"], cfg["latent_size"],
+             cfg["latent_channels"])
+    noise = jax.random.normal(jnp.asarray(key), shape, jnp.float32)
+    plain = reference.sample(
+        noise, jnp.asarray(text), stack, weights.router(SEED, cfg["router"]),
+        reference.time_grid(cfg["sampler"]["num_steps"]),
+        spec=reference.freeze(cfg), precision="highest")
+    np.testing.assert_array_equal(_ref(cfg, key, text, "highest"),
+                                  np.asarray(plain))
 
 
 def test_weights_match_the_program_layout():

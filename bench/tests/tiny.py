@@ -23,8 +23,13 @@ TINY = {
 }
 
 
-def tiny():
-    return copy.deepcopy(TINY)
+def tiny(shards: int = 1):
+    """The tiny configuration; with ``shards`` > 1 its experts placed on
+    that many devices (``expert_shards``)."""
+    cfg = copy.deepcopy(TINY)
+    if shards > 1:
+        cfg.update(name=f"tiny-ep{shards}", expert_shards=shards)
+    return cfg
 
 
 #: the tiny size reads about 1e-6 on sound runs and 6e-5 for the control
@@ -43,29 +48,40 @@ MIXES = {
 }
 
 
+#: the tiny closed mix on four devices, two experts to a device
+EP4 = "tiny-closed-ep4"
+
+
 def make_root(tmp_path, bench_dir):
     """A checkout at ``tmp_path`` holding a copy of ``bench_dir`` and a
-    ``BENCHMARK.json`` whose cells run the tiny configuration."""
+    ``BENCHMARK.json`` whose cells run the tiny configuration: one cell
+    per mix on one chip, and ``EP4`` on four."""
     import json
+    import pathlib
     import shutil
 
-    root = tmp_path / "checkout"
+    root = pathlib.Path(tmp_path) / "checkout"
     shutil.copytree(bench_dir, root / "bench",
                     ignore=shutil.ignore_patterns("__pycache__"))
-    (root / "bench" / "configs" / "tiny.json").write_text(json.dumps(tiny()))
+    for cfg in (tiny(), tiny(4)):
+        (root / "bench" / "configs" / f"{cfg['name']}.json").write_text(
+            json.dumps(cfg))
     for name, mix in MIXES.items():
         (root / "bench" / "traffic" / f"{name}.json").write_text(
             json.dumps(mix))
     bench = {
         "run_seconds": 1,
-        "configs": [{"name": "tiny", "file": "bench/configs/tiny.json"}],
+        "configs": [{"name": name, "file": f"bench/configs/{name}.json"}
+                    for name in ("tiny", "tiny-ep4")],
         "workloads": [
             {"name": name, "config": "tiny", "traffic": name, "chips": 1}
-            for name in MIXES],
+            for name in MIXES] + [
+            {"name": EP4, "config": "tiny-ep4", "traffic": "tiny-closed",
+             "chips": 4}],
         "end_to_end": [
             {"name": "setup_s", "unit": "s"},
             {"name": "img_per_s", "unit": "img/s",
-             "workloads": ["tiny-closed"]},
+             "workloads": ["tiny-closed", EP4]},
             {"name": "latency_p50_s", "unit": "s",
              "workloads": ["tiny-open"]},
         ],

@@ -11,8 +11,14 @@ sits at the zero of a fresh DiT: a leaf that starts at zero draws
 and the timestep table is the sinusoidal table plus the jitter.
 
 ``expert_list`` gives one tree per expert (what the serving engine takes),
-``expert_stack`` the same values with a leading expert axis (what the
-reference takes).  Both are single jitted programs keyed by the seed.
+``expert_blocks`` the same values as stacks with a leading expert axis
+(what the reference takes).  Given the devices of a configuration's
+``expert_shards``, both draw shard ``s`` (experts ``s * K/N`` up to
+``(s + 1) * K/N``) on ``devices[s]`` alone, so no device holds another
+shard's experts; with none, every expert is drawn on the default device.
+Each shard is one jitted program keyed by the seed, and expert ``e`` is
+drawn from ``fold_in(key, e)`` wherever it lives, so the values do not
+depend on the layout.
 """
 
 from __future__ import annotations
@@ -119,16 +125,16 @@ def _frozen(m: dict) -> tuple:
 
 
 @functools.partial(jax.jit, static_argnums=(1, 2))
-def _expert_list(key, m: tuple, n: int):
+def _expert_list(key, m: tuple, ids: tuple):
     m = dict(m)
     return [_draw(layout(m, router=False), jax.random.fold_in(key, e))
-            for e in range(n)]
+            for e in ids]
 
 
 @functools.partial(jax.jit, static_argnums=(1, 2))
-def _expert_stack(key, m: tuple, n: int):
+def _expert_stack(key, m: tuple, ids: tuple):
     m = dict(m)
-    keys = jnp.stack([jax.random.fold_in(key, e) for e in range(n)])
+    keys = jnp.stack([jax.random.fold_in(key, e) for e in ids])
     return jax.vmap(lambda k: _draw(layout(m, router=False), k))(keys)
 
 
@@ -138,13 +144,38 @@ def _router(key, m: tuple):
                  jax.random.fold_in(key, ROUTER_FOLD))
 
 
-def expert_list(seed: int, m: dict, n: int) -> list:
-    return _expert_list(seed_key(seed), _frozen(m), n)
+def _shards(n: int, devices) -> list:
+    """``(expert ids, device)`` of each shard; one shard of all ``n`` on
+    the default device (``None``) where ``devices`` is None."""
+    if devices is None:
+        return [(tuple(range(n)), None)]
+    per = n // len(devices)
+    return [(tuple(range(s * per, (s + 1) * per)), d)
+            for s, d in enumerate(devices)]
 
 
-def expert_stack(seed: int, m: dict, n: int) -> dict:
-    return _expert_stack(seed_key(seed), _frozen(m), n)
+def expert_list(seed: int, m: dict, n: int, devices=None) -> list:
+    """One tree per expert; expert ``e`` on ``devices[e // (n / N)]``,
+    uncommitted there, so that a program which gathers the list still
+    may."""
+    key, m = seed_key(seed), _frozen(m)
+    out = []
+    for ids, device in _shards(n, devices):
+        with jax.default_device(device):
+            out += _expert_list(key, m, ids)
+    return out
 
 
-def router(seed: int, m: dict) -> dict:
-    return _router(seed_key(seed), _frozen(m))
+def expert_blocks(seed: int, m: dict, n: int, devices=None) -> list:
+    """The experts as one stack per shard, each on its shard's device."""
+    key, m = seed_key(seed), _frozen(m)
+    out = []
+    for ids, device in _shards(n, devices):
+        with jax.default_device(device):
+            out.append(_expert_stack(key, m, ids))
+    return out
+
+
+def router(seed: int, m: dict, device=None) -> dict:
+    with jax.default_device(device):
+        return _router(seed_key(seed), _frozen(m))
