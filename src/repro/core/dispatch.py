@@ -54,6 +54,7 @@ from typing import Callable, Protocol, Sequence, runtime_checkable
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core.conversion import ConversionConfig
 from repro.core.param_store import DenseStore, ExpertParamStore, as_store
@@ -599,6 +600,11 @@ class RaggedExecutor(_FusedVelocity):
       per pair and broadcasts to the replicas (the grouped backend's
       black-box ``apply_fn`` contract cannot see that structure).
 
+    * on a mesh whose ``"expert"`` axis spans several devices the apply
+      runs inside one ``shard_map`` (``_expert_parallel_apply``): each
+      device runs every pair against its own experts' leaves, and one
+      ``psum`` a step (``_exchange``) joins the devices' predictions.
+
     Dense float32 stores are bitwise-identical to the grouped backend;
     quantized stores match within the store's quantization error.
     Membership (``valid``) stays traced data: hot add/evict reaches
@@ -640,14 +646,69 @@ class RaggedExecutor(_FusedVelocity):
         ts = t_all[sample_ids][rep]                        # (P,)
         cs = {key: v[sample_ids][pg_pos] for key, v in cond_all.items()}
 
-        view = self.store.ragged_view()
-        out = self.ragged_apply_fn(view, xs, ts, cs, pe, g)  # (P·g, ...)
+        mesh = expert_parallel_mesh()
+        if mesh is None:
+            out = self.ragged_apply_fn(self.store.ragged_view(), xs, ts, cs,
+                                       pe, g)              # (P·g, ...)
+        else:
+            out = _expert_parallel_apply(self.ragged_apply_fn, self.store,
+                                         xs, ts, cs, pe, g, mesh)
         out = out.reshape((npair, g) + out.shape[1:])
         preds_sorted = out[pair, gidx]                     # (N, *latent)
         preds_flat = preds_sorted[p.unsort_order]
         preds = preds_flat.reshape((g * b, k) + preds_flat.shape[1:])
         preds = jnp.moveaxis(preds, 1, 0)                  # (k, g·B, ...)
         return preds, p.slot_w, p.slot_idx
+
+
+def expert_parallel_mesh():
+    """The ambient mesh if its ``"expert"`` axis spans several devices and
+    the trace is not already inside a ``shard_map`` over it, else None."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.are_all_axes_manual \
+            or dict(mesh.shape).get("expert", 1) == 1:
+        return None
+    return mesh
+
+
+def _exchange(out: Array) -> Array:
+    """The one cross-chip collective of an expert-parallel step: each
+    pair's prediction is non-zero on the one shard that owns its expert,
+    so the sum over ``"expert"`` is exact."""
+    with jax.named_scope("expert_exchange"):
+        return jax.lax.psum(out, "expert")
+
+
+def shard_share(apply_fn, store, xs, ts, cs, pe, g):
+    """One shard's share of a step's ragged predictions, inside a
+    ``shard_map`` over ``"expert"``: ``store`` holds this shard's experts
+    only.  Each pair's global expert id maps to a local one, the forward
+    runs for all ``P`` pair slots against the local leaves (the work does
+    not depend on how the router splits the pairs among shards), and the
+    pairs another shard owns are zeroed.  Returns ``(P·g, ...)``."""
+    n_local = jax.tree.leaves(store)[0].shape[0]
+    lo = jax.lax.axis_index("expert") * n_local
+    own = (pe >= lo) & (pe < lo + n_local)
+    out = apply_fn(store.ragged_view(), xs, ts, cs,
+                   jnp.where(own, pe - lo, 0), g)
+    keep = jnp.repeat(own, g).reshape((-1,) + (1,) * (out.ndim - 1))
+    return jnp.where(keep, out, 0.0)
+
+
+def _expert_parallel_apply(apply_fn, store, xs, ts, cs, pe, g, mesh):
+    """The ragged apply of one step on an expert-sharded store: one
+    ``shard_map`` over the mesh, store leaves ``P("expert")``, the step's
+    pairs, timesteps, conditioning and global expert ids ``pe``
+    replicated; each device computes its ``shard_share`` and
+    ``_exchange`` sums them.  No weight leaf crosses devices, and the
+    forward holds no collective."""
+    def shard(store, xs, ts, cs, pe):
+        return _exchange(shard_share(apply_fn, store, xs, ts, cs, pe, g))
+
+    return jax.shard_map(
+        shard, mesh=mesh, in_specs=(P("expert"), P(), P(), P(), P()),
+        out_specs=P(), check_vma=False,
+    )(store, xs, ts, cs, pe)
 
 
 # ---------------------------------------------------------------------------

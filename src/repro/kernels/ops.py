@@ -44,17 +44,21 @@ def _interpret() -> bool:
 
 
 def _launch(kernel, *args):
-    """Run one Pallas launch, replicated across the ambient mesh.
+    """Run one Pallas launch on the ambient mesh.
 
     Mosaic kernels cannot be partitioned by the compiler.  Inside a
-    multi-device mesh (sharded serving traces its programs under
-    ``jax.sharding.use_abstract_mesh``) the launch therefore runs under
+    ``shard_map`` body every mesh axis is manual and the operands are
+    already each device's own (the expert-parallel ragged apply,
+    ``core.dispatch.RaggedExecutor``, hands its launches the local
+    experts' leaves), so the launch is a plain call there.  Elsewhere on
+    a multi-device mesh (sharded serving traces its programs under
+    ``jax.sharding.use_abstract_mesh``) the launch runs under
     ``shard_map`` with every operand replicated: each device gathers the
     operands and runs the whole launch.  Without a mesh it is a plain
     call.
     """
     mesh = jax.sharding.get_abstract_mesh()
-    if mesh.empty or mesh.size == 1:
+    if mesh.empty or mesh.size == 1 or mesh.are_all_axes_manual:
         return kernel(*args)
     return jax.shard_map(kernel, mesh=mesh, in_specs=P(), out_specs=P(),
                          check_vma=False)(*args)

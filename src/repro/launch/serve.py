@@ -67,19 +67,26 @@ Topology
 The sharded engine lives on an ``("expert", "data")`` mesh
 (``launch.mesh.make_expert_mesh``):
 
-* the stacked expert pytree (leaves ``(K, ...)``,
-  ``models.dit.stack_expert_params``) shards its leading K axis over
-  "expert" — each device group holds ``K / n_expert_shards`` resident
-  experts (DDM/Paris-style placement: experts are *placed across*
-  devices, not replicated per host);
+* the stacked expert store (leaves ``(K, ...)``, ``core.param_store``)
+  shards its leading K axis over "expert" — each device group holds
+  ``K / n_expert_shards`` resident experts (DDM/Paris-style placement:
+  experts are *placed across* devices, not replicated per host).  With
+  ``n_expert_shards > 1`` the store is built shard by shard
+  (``ServingEngine._place_experts``, host span ``engine.place_experts``):
+  each shard's experts are stacked on their own device and the shards
+  joined into sharded arrays, so no device ever holds another shard's
+  experts, not even during set-up;
 * request batches (initial noise, text embeddings, the evolving latent
   state) shard their leading batch dim over "data";
-* per-step routed dispatch gathers the k selected experts' params from
-  their owning shards — GSPMD lowers the stacked-axis gather to an
-  all-gather of just those slices over the "expert" axis — and the fused
-  velocity/Euler update runs data-parallel on the batch shards
-  (``core.sampling`` re-constrains the latent to the "data" axis every
-  step);
+* the ragged backend runs each step's expert forward inside one
+  ``shard_map`` over the mesh (``core.dispatch.RaggedExecutor``): every
+  device runs all ``B·k`` routed pairs against its own experts' leaves,
+  zeroes the pairs it does not own, and one ``psum`` over "expert"
+  (device scope ``expert_exchange``) joins the predictions — no expert
+  weight crosses devices, and the forward holds no collective.  The
+  router, CFG and the fused convert/Euler step run replicated.  The
+  gathered and grouped backends still resolve routed slices through
+  GSPMD, which gathers them from their owning shards;
 * the single-host path is the degenerate 1×1 mesh (or ``mesh=None``) and
   is bit-identical to unsharded serving.
 
@@ -377,10 +384,32 @@ class ServingEngine:
                     f"{self.homogeneous}, strategy="
                     f"{self.sampler.strategy!r}, engine={self.engine!r}"
                 )
-        self.param_store = (
-            make_store(D.stack_expert_params(self.expert_params), dtype=pd)
-            if self.homogeneous and self.expert_params else None
-        )
+        self.mesh = None
+        if self.n_expert_shards != 1 or self.n_data_shards is not None:
+            slots = self.capacity or len(self.experts)
+            if self.n_expert_shards > 1 and (
+                len(self.experts) % self.n_expert_shards
+                or slots % self.n_expert_shards
+            ):
+                # sanitize_spec would silently fall back to replicating
+                # the expert axis — zero memory savings while reporting a
+                # sharded mesh; make the misconfiguration loud instead.
+                raise ValueError(
+                    f"n_expert_shards={self.n_expert_shards} does not "
+                    f"divide the {len(self.experts)}-expert ensemble "
+                    f"({slots} slots); expert placement would silently "
+                    f"replicate"
+                )
+            self.mesh = make_expert_mesh(self.n_expert_shards,
+                                         self.n_data_shards)
+        with TraceAnnotation("engine.place_experts"):
+            self.param_store = None
+            if self.homogeneous and self.expert_params:
+                self.param_store = (
+                    self._place_experts(pd) if self.n_expert_shards > 1
+                    else make_store(D.stack_expert_params(
+                        self.expert_params), dtype=pd)
+                )
         # Slot template for integrity-validating incoming checkpoints
         # (captured before a quantized store drops the fp list).
         self._slot_template = None
@@ -414,25 +443,57 @@ class ServingEngine:
             # A pytree callable can be a jit argument (its bound arrays,
             # if any, are traced rather than baked into the program).
             self.router_fn = jax.tree_util.Partial(self.router_fn)
-        self.mesh = None
-        if self.n_expert_shards != 1 or self.n_data_shards is not None:
-            if self.n_expert_shards > 1 and \
-                    len(self.experts) % self.n_expert_shards != 0:
-                # sanitize_spec would silently fall back to replicating
-                # the expert axis — zero memory savings while reporting a
-                # sharded mesh; make the misconfiguration loud instead.
-                raise ValueError(
-                    f"n_expert_shards={self.n_expert_shards} does not "
-                    f"divide the {len(self.experts)}-expert ensemble; "
-                    f"expert placement would silently replicate"
-                )
-            self.mesh = make_expert_mesh(self.n_expert_shards,
-                                         self.n_data_shards)
+        if self.mesh is not None:
             if self.param_store is not None:
                 self.param_store = self._put_store(self.param_store)
             self.router_fn = jax.device_put(
                 self.router_fn, NamedSharding(self.mesh, P())
             )
+
+    def _place_experts(self, pd: str):
+        """The stacked store of an expert mesh, built shard by shard.
+
+        Shard ``s`` holds capacity slots ``s·K_cap/N … (s+1)·K_cap/N − 1``
+        (``K_cap`` is ``capacity`` on an elastic engine, else K): its
+        experts are moved to the device at mesh row ``s``, stacked and
+        stored (quantized, capacity-padded) there, so a scale or padding
+        leaf lives with its shard and no device ever holds another
+        shard's experts.  The shards' leaves are then joined into
+        ``(K_cap, …)`` arrays sharded ``P("expert")`` without a copy
+        (replicated over "data" on a mesh that has it).  The validity mask
+        is left to ``_init_elastic``.
+        """
+        rows = self.mesh.devices                      # (N, data) devices
+        n, k = rows.shape[0], len(self.expert_params)
+        per = (self.capacity or k) // n
+        shards = []
+        for s in range(n):
+            home = rows[s, 0]
+            ids = range(s * per, min((s + 1) * per, k))
+            with jax.default_device(home):
+                params = [jax.device_put(self.expert_params[e], home)
+                          for e in ids]
+                if not params:              # capacity pad only: a 0-stack
+                    params = [jax.tree.map(
+                        lambda x: jnp.zeros(np.shape(x), x.dtype),
+                        self.expert_params[0])]
+                store = make_store(D.stack_expert_params(params), dtype=pd)
+                if not ids:
+                    store = store.static_slice(0, 0)
+                if store.num_experts < per:
+                    store = pad_to_capacity(store, per)
+            shards.append(store.with_valid(None))
+        sharding = NamedSharding(self.mesh, P("expert"))
+
+        def join(*parts):
+            arrays = [part if d == 0 else jax.device_put(part, rows[s, d])
+                      for s, part in enumerate(parts)
+                      for d in range(rows.shape[1])]
+            return jax.make_array_from_single_device_arrays(
+                (n * per,) + parts[0].shape[1:], sharding, arrays)
+
+        return dataclasses.replace(jax.tree.map(join, *shards),
+                                   num_experts=n * per)
 
     def _put_store(self, store):
         """Place a store on the expert mesh (no-op unsharded).
@@ -600,7 +661,9 @@ class ServingEngine:
                 schedule="linear", cluster_id=0,
             ))
         self.expert_health = health + ["EMPTY"] * (self.capacity - k0)
-        self.param_store = pad_to_capacity(self.param_store, self.capacity)
+        if self.param_store.num_experts < self.capacity:
+            self.param_store = pad_to_capacity(self.param_store,
+                                               self.capacity)
         mask = jnp.array([h == "ACTIVE" for h in self.expert_health])
         self.param_store = self.param_store.with_valid(mask)
         self._refresh_membership_arrays()

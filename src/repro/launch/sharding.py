@@ -261,9 +261,11 @@ def expert_param_specs(
 
     The leading expert axis shards over the mesh's "expert" axis so each
     device group holds only ``K / n_expert_shards`` resident experts; all
-    trailing (weight) dims replicate — the routed engine's per-step gather
-    of the k selected experts' params then lowers to an all-gather over
-    the expert axis of just those slices.
+    trailing (weight) dims replicate.  The ragged backend reads each
+    device's own experts inside a ``shard_map`` and exchanges
+    predictions, never weights (``core.dispatch.RaggedExecutor``); the
+    gathered and grouped backends leave the routed slices to GSPMD, which
+    gathers them from the owning shards.
 
     ``logical_axes`` optionally supplies per-leaf axis-name annotations
     (``models.dit.stacked_param_logical_axes`` / ``ExpertParamStore.
@@ -308,14 +310,15 @@ def dispatch_plan_sharding(mesh: Mesh) -> NamedSharding:
     assignment order, per-expert segment offsets) replicates across the
     mesh: every shard needs the full plan to slice its resident experts'
     groups (grouped backend), gather its param slices (gathered backend),
-    or build the pair-major per-row expert ids that drive the one-kernel
-    ragged GEMM's weight gathers (ragged backend — the per-tile expert
-    ids are derived from the plan's sort order, so the plan must be
-    whole on every shard), and the arrays are O(B·k) ints — replication
-    costs nothing next to the latents.  Constraining them explicitly
-    keeps GSPMD from threading a sharded batch axis into the executor's
-    per-expert branches, which would force collectives inside every
-    bucket branch (grouped) or every weight gather (ragged).
+    or build the pair-major expert ids of all ``B·k`` pairs (ragged
+    backend — on an expert mesh each device maps them to its own local
+    experts' ids and masks the pairs it does not own), and the arrays
+    are O(B·k) ints — replication costs nothing next to the latents.
+    Constraining them explicitly keeps GSPMD from threading a sharded
+    batch axis into the executor's per-expert branches, which would
+    force collectives inside every bucket branch (grouped) or inside the
+    ragged forward, whose one collective is the exchange of predictions
+    at its end.
     """
     return NamedSharding(mesh, P())
 
@@ -325,8 +328,11 @@ def mesh_scope(mesh: Mesh | None):
 
     The hot-path kernels (``kernels.ops``) look for this ambient mesh:
     the TPU compiler cannot partition a Pallas launch, so on a
-    multi-device mesh each launch runs under ``shard_map``.  No mesh —
-    no context.
+    multi-device mesh each launch runs replicated under ``shard_map``,
+    except inside the expert-parallel ragged apply's own ``shard_map``,
+    where it is a plain call on each device's operands.  The executor
+    reads the mesh's "expert" axis from it too
+    (``core.dispatch.expert_parallel_mesh``).  No mesh — no context.
     """
     if mesh is None:
         return contextlib.nullcontext()

@@ -124,9 +124,20 @@ def run(x, w, e, preds, xt, wts, coef):
     return (ops.ragged_expert_matmul(x, w, e),
             ops.fused_step(preds, xt, wts, coef, 0.1, g=2, cfg_scale=7.5))
 
-def on_mesh(*a):
+def own_experts(x, w, e):
+    # inside the shard_map: w is this device's 2 experts, e global ids
+    lo = jax.lax.axis_index("expert") * w.shape[0]
+    mine = (e >= lo) & (e < lo + w.shape[0])
+    y = ops.ragged_expert_matmul(x, w, jnp.where(mine, e - lo, 0))
+    return jax.lax.psum(jnp.where(mine[:, None, None], y, 0.0), "expert")
+
+def on_mesh(x, w, e, preds, xt, wts, coef):
     with mesh_scope(mesh):
-        return run(*a)
+        y = jax.shard_map(own_experts, mesh=mesh,
+                          in_specs=(P(), P("expert"), P()), out_specs=P(),
+                          check_vma=False)(x, w, e)
+        return y, ops.fused_step(preds, xt, wts, coef, 0.1, g=2,
+                                 cfg_scale=7.5)
 
 args = (x, w, e, preds, xt, wts, coef)
 plain = jax.jit(run)(*args)
@@ -134,15 +145,22 @@ w_sh = jax.device_put(w, NamedSharding(mesh, P("expert")))
 meshed = jax.jit(on_mesh)(x, w_sh, *args[2:])
 for a, b in zip(plain, meshed):
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+hlo = jax.jit(on_mesh).lower(x, w_sh, *args[2:]).compile().as_text()
+assert "all-gather" not in hlo, "a weight leaf was gathered"
+assert hlo.count(" all-reduce(") == 1, "one exchange of the products"
 print("mesh kernels ok")
 """
 
 
 def test_pallas_launches_run_replicated_on_a_mesh():
-    """The TPU compiler cannot partition a Pallas launch, so on a mesh
-    every hot-path kernel runs under ``shard_map`` with replicated
-    operands — here in interpret mode on a forced 2-device CPU host,
-    with expert-sharded weights, bitwise equal to the meshless call."""
+    """The TPU compiler cannot partition a Pallas launch.  On a mesh a
+    launch outside any ``shard_map`` (here the fused step) runs
+    replicated under ``shard_map``; inside the expert-parallel
+    ``shard_map`` every axis is manual and a launch (here the ragged
+    GEMM over each device's own two experts) is a plain call on local
+    operands, so no weight leaf is gathered and one ``psum`` joins the
+    products.  Interpret mode on a forced 2-device CPU host; both
+    bitwise equal to the meshless calls."""
     env = dict(os.environ)
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
