@@ -602,8 +602,9 @@ class RaggedExecutor(_FusedVelocity):
 
     * on a mesh whose ``"expert"`` axis spans several devices the apply
       runs inside one ``shard_map`` (``_expert_parallel_apply``): each
-      device runs every pair against its own experts' leaves, and one
-      ``psum`` a step (``_exchange``) joins the devices' predictions.
+      device runs the pairs routed to its own experts (the expert GEMMs
+      skip the other pairs' row tiles), and one ``psum`` a step
+      (``_exchange``) joins the devices' predictions.
 
     Dense float32 stores are bitwise-identical to the grouped backend;
     quantized stores match within the store's quantization error.
@@ -682,15 +683,17 @@ def _exchange(out: Array) -> Array:
 def shard_share(apply_fn, store, xs, ts, cs, pe, g):
     """One shard's share of a step's ragged predictions, inside a
     ``shard_map`` over ``"expert"``: ``store`` holds this shard's experts
-    only.  Each pair's global expert id maps to a local one, the forward
-    runs for all ``P`` pair slots against the local leaves (the work does
-    not depend on how the router splits the pairs among shards), and the
-    pairs another shard owns are zeroed.  Returns ``(P·g, ...)``."""
+    only.  Each pair's global expert id maps to a local one, and the
+    forward keeps all ``P`` pair slots (static shapes) but is told which
+    pairs this shard owns (``live``): the expert GEMMs skip the row tiles
+    of the others, so a shard's GEMM work follows its share of the
+    router's split.  The pairs another shard owns are zeroed.  Returns
+    ``(P·g, ...)``."""
     n_local = jax.tree.leaves(store)[0].shape[0]
     lo = jax.lax.axis_index("expert") * n_local
     own = (pe >= lo) & (pe < lo + n_local)
     out = apply_fn(store.ragged_view(), xs, ts, cs,
-                   jnp.where(own, pe - lo, 0), g)
+                   jnp.where(own, pe - lo, 0), g, live=own)
     keep = jnp.repeat(own, g).reshape((-1,) + (1,) * (out.ndim - 1))
     return jnp.where(keep, out, 0.0)
 

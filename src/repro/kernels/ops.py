@@ -342,6 +342,7 @@ def ragged_expert_matmul(
     *,
     bias: Array | None = None,       # (K, F) stacked bias, optional
     w_scale: Array | None = None,    # (K,) per-expert scales (quant only)
+    live: Array | None = None,       # (P,) bool, False = group not needed
 ) -> Array:
     """Grouped expert dense: ``y[p] = x[p] @ w[expert_ids[p]] (+ bias)``.
 
@@ -366,6 +367,16 @@ def ragged_expert_matmul(
     with the exact ``hetero_fuse_dequant`` float32 multiply first, so
     the fallback is bitwise-consistent with the grouped backend's
     store-dequant path.  Output is float32 ``(P, ..., F)``.
+
+    ``live`` marks the groups whose rows are read (on an expert mesh,
+    the pairs this shard owns); the other groups' rows are not
+    meaningful.  On the Pallas path a dead group's row tiles skip the
+    MXU and their input DMAs (``ragged_gemm``'s ``tile_live``) and come
+    out zero before the bias.  The fallback computes every group, as
+    without ``live``: a select on its output would change how XLA fuses
+    the ops around it, and with that the rounding of the live rows.  On
+    both paths live groups are bitwise what the call without ``live``
+    gives.
     """
     p = x.shape[0]
     d = x.shape[-1]
@@ -391,6 +402,9 @@ def ragged_expert_matmul(
             with jax.named_scope("layer_weights"):
                 wp = jnp.pad(w, ((0, 0), (0, 0), (0, fp - f)))
         tile_e = jnp.repeat(expert_ids, m // bm)
+        kernel = functools.partial(
+            _ragged_gemm, block_m=bm, block_f=bf, interpret=_interpret(),
+        )
         if quantized:
             x32 = xf.astype(jnp.float32)
             qmax = 127.0 if is_int8 else 448.0
@@ -400,15 +414,15 @@ def ragged_expert_matmul(
                 xq = jnp.clip(jnp.round(xq), -127, 127).astype(jnp.int8)
             else:
                 xq = xq.astype(jnp.float8_e4m3fn)
-            y = _launch(functools.partial(
-                _ragged_gemm, block_m=bm, block_f=bf,
-                interpret=_interpret(),
-            ), xq, wp, tile_e, xs, w_scale)
+            operands = (xq, wp, tile_e, xs, w_scale)
         else:
-            y = _launch(functools.partial(
-                _ragged_gemm, block_m=bm, block_f=bf,
-                interpret=_interpret(),
-            ), xf, wp, tile_e)
+            operands = (xf, wp, tile_e)
+        if live is None:
+            y = _launch(kernel, *operands)
+        else:
+            tile_live = jnp.repeat(live.astype(jnp.int32), m // bm)
+            y = _launch(lambda tl, *a: kernel(*a, tile_live=tl),
+                        tile_live, *operands)
         if fp != f:
             y = y[:, :f]
         y = y.reshape((p,) + mids + (f,))

@@ -41,8 +41,8 @@ from jax.experimental.pallas import tpu as pltpu
 Array = jax.Array
 
 
-def _dense_body(e_ref, x_ref, w_ref, o_ref, *cnt):
-    del e_ref                       # expert id consumed by the index map
+def _dense_body(e_ref, x_ref, w_ref, o_ref, *cnt, i=None):
+    del e_ref, i                    # expert id consumed by the index map
     o_ref[...] = jnp.dot(
         x_ref[...].astype(jnp.float32),
         w_ref[0].astype(jnp.float32),
@@ -53,8 +53,9 @@ def _dense_body(e_ref, x_ref, w_ref, o_ref, *cnt):
 
 
 def _quant_body(acc_dtype, e_ref, ws_ref, x_ref, xs_ref, w_ref, o_ref,
-                *cnt):
-    i = pl.program_id(0)
+                *cnt, i=None):
+    if i is None:
+        i = pl.program_id(0)
     acc = jax.lax.dot_general(
         x_ref[...], w_ref[0],
         dimension_numbers=(((1,), (0,)), ((), ())),
@@ -69,6 +70,44 @@ def _quant_body(acc_dtype, e_ref, ws_ref, x_ref, xs_ref, w_ref, o_ref,
         cnt[0][...] = jnp.ones_like(cnt[0])
 
 
+def _live_tiles_only(body, n_prefetch, n_out, *refs):
+    """``body`` on a live row tile; a dead one writes zero blocks and does
+    no MXU work.  ``refs`` are ``body``'s scalar-prefetch refs, then the
+    masked launch's two (``src``, ``col``; a tile is live where ``col``
+    is negative), then ``body``'s input and output refs.  The row tile's
+    index is read here, outside the branch, as interpret mode requires."""
+    col_ref = refs[n_prefetch + 1]
+    rest = refs[n_prefetch + 2:]
+    i = pl.program_id(0)
+    live = col_ref[i] < 0
+
+    @pl.when(live)
+    def _():
+        body(*refs[:n_prefetch], *rest, i=i)
+
+    @pl.when(jnp.logical_not(live))
+    def _():
+        for o in rest[len(rest) - n_out:]:
+            o[...] = jnp.zeros(o.shape, o.dtype)
+
+
+def _dead_tile_sources(tile_live: Array, gf: int) -> tuple[Array, Array]:
+    """``(src, col)`` per row tile: the row tile and output-lane tile whose
+    input blocks a tile reads.  A live tile reads its own row block at
+    every lane tile (``src = i``, ``col = -1``).  A dead tile re-points at
+    blocks the pipeline already holds in VMEM, so it fetches nothing:
+    those of the last live tile before it at that tile's last lane tile,
+    or, before the first live tile, those of the first live tile at lane
+    tile 0, which that tile then reads without a fetch of its own."""
+    live = tile_live != 0
+    idx = jnp.arange(live.shape[0], dtype=jnp.int32)
+    last = jax.lax.cummax(jnp.where(live, idx, -1))
+    first = jnp.argmax(live).astype(jnp.int32)
+    src = jnp.where(last >= 0, last, first)
+    col = jnp.where(live, -1, jnp.where(last >= 0, gf - 1, 0))
+    return src, col.astype(jnp.int32)
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("block_m", "block_f", "interpret", "debug"),
@@ -80,6 +119,7 @@ def ragged_gemm(
     x_scale: Array | None = None,   # (M,) per-row act scales (quant only)
     w_scale: Array | None = None,   # (K,) per-expert weight scales
     *,
+    tile_live: Array | None = None,  # (M // block_m,) int32, 0 = dead tile
     block_m: int,
     block_f: int,
     interpret: bool = False,
@@ -95,11 +135,19 @@ def ragged_gemm(
     native), then the fused dequant epilogue applies
     ``x_scale[row] · w_scale[expert]``.  Output is float32 ``(M, F)``.
 
+    ``tile_live`` marks row tiles whose rows nobody reads (the pairs
+    another shard owns, on an expert mesh): a dead tile's output is
+    exactly zero, it runs no ``dot``, and its input blocks point at
+    blocks already in VMEM (:func:`_dead_tile_sources`), so it costs no
+    DMA either.  Live tiles are bitwise what the unmasked launch gives.
+    Without it the launch has no liveness operand and no branch.
+
     ``debug=True`` returns ``(y, tiles)`` where ``tiles`` is an
-    ``(M/block_m, F/block_f)`` int32 map with a 1 per executed grid
-    step — the runtime proof that empty segments cost zero tiles.  Each
-    grid step writes a whole ``(8, 128)`` counter tile (the smallest
-    int32 block the TPU lowering accepts); the map is its corners.
+    ``(M/block_m, F/block_f)`` int32 map with a 1 per grid step that ran
+    the contraction — the runtime proof that empty segments and dead
+    tiles cost zero tiles.  Each grid step writes a whole ``(8, 128)``
+    counter tile (the smallest int32 block the TPU lowering accepts);
+    the map is its corners.
     """
     m, d = x.shape
     k_cap, dw, f = w.shape
@@ -129,7 +177,8 @@ def ragged_gemm(
         )
         out_specs.append(pl.BlockSpec((8, 128), lambda i, j, *pf: (i, j)))
 
-    tile_experts = tile_experts.astype(jnp.int32)
+    prefetch = [tile_experts.astype(jnp.int32)]
+    operands = [x]
     if quantized:
         if x.dtype != w.dtype:
             raise ValueError(
@@ -138,39 +187,52 @@ def ragged_gemm(
             )
         if x_scale is None or w_scale is None:
             raise ValueError("quantized ragged GEMM needs x_scale + w_scale")
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(gm, gf),
-            in_specs=[
-                pl.BlockSpec((block_m, d), lambda i, j, e, s: (i, 0)),
-                pl.BlockSpec((block_m, 1), lambda i, j, e, s: (i, 0)),
-                pl.BlockSpec((1, d, block_f),
-                             lambda i, j, e, s: (e[i], 0, j)),
-            ],
-            out_specs=out_specs,
-        )
+        prefetch.append(w_scale.astype(jnp.float32))
+        operands.append(x_scale.astype(jnp.float32).reshape(m, 1))
         body = functools.partial(
             _quant_body, jnp.int32 if is_int8 else jnp.float32
         )
-        out = pl.pallas_call(
-            body, grid_spec=grid_spec, out_shape=out_shape,
-            interpret=interpret, name="ragged_gemm",
-        )(tile_experts, w_scale.astype(jnp.float32),
-          x, x_scale.astype(jnp.float32).reshape(m, 1), w)
     else:
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(gm, gf),
-            in_specs=[
-                pl.BlockSpec((block_m, d), lambda i, j, e: (i, 0)),
-                pl.BlockSpec((1, d, block_f), lambda i, j, e: (e[i], 0, j)),
-            ],
-            out_specs=out_specs,
-        )
-        out = pl.pallas_call(
-            _dense_body, grid_spec=grid_spec, out_shape=out_shape,
-            interpret=interpret, name="ragged_gemm",
-        )(tile_experts, x, w)
+        body = _dense_body
+    operands.append(w)
+
+    def row_block(i, j, *pf):
+        return i, 0
+
+    def weight_block(i, j, e, *pf):
+        return e[i], 0, j
+
+    if tile_live is not None:
+        if tile_live.shape != (gm,):
+            raise ValueError(
+                f"tile_live must be ({gm},), got {tile_live.shape}"
+            )
+        src, col = _dead_tile_sources(tile_live, gf)
+        body = functools.partial(_live_tiles_only, body, len(prefetch),
+                                 len(out_shape))
+        prefetch = [prefetch[0][src]] + prefetch[1:] + [src, col]
+
+        def row_block(i, j, *pf):
+            return pf[-2][i], 0
+
+        def weight_block(i, j, e, *pf):
+            c = pf[-1][i]
+            return e[i], 0, jnp.where(c < 0, j, c)
+
+    in_specs = [pl.BlockSpec((block_m, d), row_block)]
+    if quantized:
+        in_specs.append(pl.BlockSpec((block_m, 1), row_block))
+    in_specs.append(pl.BlockSpec((1, d, block_f), weight_block))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(gm, gf),
+        in_specs=in_specs,
+        out_specs=out_specs,
+    )
+    out = pl.pallas_call(
+        body, grid_spec=grid_spec, out_shape=out_shape,
+        interpret=interpret, name="ragged_gemm",
+    )(*prefetch, *operands)
     if debug:
         return out[0], out[1][::8, ::128]
     return out[0]

@@ -162,6 +162,8 @@ def ref_ragged_gemm(
     tile_experts: Array,      # (M // block_m,) int32 expert id per row tile
     x_scale: Array | None = None,   # (M,) per-row activation scales
     w_scale: Array | None = None,   # (K,) per-expert weight scales
+    *,
+    tile_live: Array | None = None,  # (M // block_m,) int32, 0 = dead tile
 ) -> Array:
     """Oracle for the ragged grouped expert GEMM with fused dequant.
 
@@ -173,7 +175,8 @@ def ref_ragged_gemm(
     exact integers) and fp8×fp8 in float32, then the dequant epilogue
     applies ``x_scale[row] · w_scale[expert]`` in float32 — the same
     multiply order as the kernel, so the int8 path is bitwise
-    comparable.  Output is float32 ``(M, F)``.
+    comparable.  Rows of a dead tile (``tile_live`` 0) are zero.  Output
+    is float32 ``(M, F)``.
     """
     m = x.shape[0]
     gm = tile_experts.shape[0]
@@ -192,6 +195,8 @@ def ref_ragged_gemm(
     if x_scale is not None and w_scale is not None:
         out = (out * x_scale.astype(jnp.float32)[:, None]) \
             * w_scale.astype(jnp.float32)[row_e][:, None]
+    if tile_live is not None:
+        out = jnp.where(jnp.repeat(tile_live != 0, bm)[:, None], out, 0.0)
     return out
 
 

@@ -194,6 +194,21 @@ def _validate_expert_params(params, template, path: str) -> None:
             )
 
 
+def _count_shard_rows(bump, rows) -> None:
+    """Report an expert shard's executed rows from inside the
+    expert-parallel ``shard_map``: ``bump(rows, shard, busiest,
+    first_replica)``, where ``busiest`` is the most rows any shard ran
+    this step.  Every device reports; only the first replica along the
+    mesh's other axes is counted, once per shard."""
+    mesh = jax.sharding.get_abstract_mesh()
+    first = jnp.bool_(True)
+    for axis in mesh.axis_names:
+        if axis != "expert":
+            first &= jax.lax.axis_index(axis) == 0
+    jax.debug.callback(bump, rows, jax.lax.axis_index("expert"),
+                       jax.lax.pmax(rows, "expert"), first)
+
+
 @dataclasses.dataclass
 class PendingRequest:
     """Handle returned by ``ServingEngine.submit``; resolved by ``flush``.
@@ -323,8 +338,11 @@ class ServingEngine:
     #: ``stats['padded_model_rows']`` tracks rows the backend *executed*
     #: (grouped: power-of-two bucket padding included; ragged: exactly
     #: the routed rows) against the ``routed_model_rows`` the plans
-    #: asked for — read via :meth:`padding_stats`.  Off by default: the
-    #: callback forces host sync points on the hot path.
+    #: asked for — read via :meth:`padding_stats`.  On an expert mesh
+    #: each shard counts the rows it ran (its owned pairs × g), in
+    #: ``shard_model_rows``, and the busiest shard's, in
+    #: ``busiest_shard_model_rows``.  Off by default: the callback forces
+    #: host sync points on the hot path.
     track_padding: bool = False
 
     def __post_init__(self) -> None:
@@ -340,7 +358,8 @@ class ServingEngine:
                       "quarantined_checkpoints": 0, "degraded_steps": 0,
                       "request_requeues": 0, "failed_requests": 0,
                       "padded_model_rows": 0, "routed_model_rows": 0,
-                      "model_steps": 0,
+                      "model_steps": 0, "shard_model_rows": {},
+                      "busiest_shard_model_rows": 0,
                       "deadline_exceeded": 0, "watchdog_trips": 0,
                       "breaker_trips": 0, "breaker_probes": 0,
                       "breaker_restores": 0, "journal_snapshots": 0}
@@ -536,8 +555,16 @@ class ServingEngine:
                 "executor, which has no dispatch padding to observe"
             )
 
-        def _bump(rows):
-            self.stats["padded_model_rows"] += int(rows)
+        def _bump(rows, shard=0, busiest=None, first_replica=True):
+            if not first_replica:
+                return
+            rows, shard = int(rows), int(shard)
+            self.stats["padded_model_rows"] += rows
+            per_shard = self.stats["shard_model_rows"]
+            per_shard[shard] = per_shard.get(shard, 0) + rows
+            if shard == 0:
+                self.stats["busiest_shard_model_rows"] += (
+                    rows if busiest is None else int(busiest))
 
         base_apply = self.experts[0].apply_fn
 
@@ -548,9 +575,13 @@ class ServingEngine:
         base_ragged = getattr(self.experts[0], "ragged_apply_fn", None)
         counted_ragged = None
         if base_ragged is not None:
-            def counted_ragged(view, x_p, t_p, cond, pe, g):
-                jax.debug.callback(_bump, x_p.shape[0] * g)
-                return base_ragged(view, x_p, t_p, cond, pe, g)
+            def counted_ragged(view, x_p, t_p, cond, pe, g, live=None):
+                if live is None:
+                    jax.debug.callback(_bump, x_p.shape[0] * g)
+                else:
+                    _count_shard_rows(_bump, jnp.sum(live, dtype=jnp.int32)
+                                      * g)
+                return base_ragged(view, x_p, t_p, cond, pe, g, live=live)
 
         self.experts = [
             dataclasses.replace(e, apply_fn=counted_apply,
@@ -577,7 +608,11 @@ class ServingEngine:
 
         ``padded_rows_per_step`` is the runtime-executed row count per
         sampling step; ``padding_overhead`` is executed/routed − 1 (the
-        grouped backend's bucket padding tax; 0.0 under ``ragged``).
+        grouped backend's bucket padding tax; 0.0 under ``ragged``, on
+        an expert mesh too, where each shard runs only its owned pairs).
+        ``shard_rows_per_step`` splits the executed rows by expert shard
+        and ``busiest_shard_rows_per_step`` is the busiest shard's rows,
+        averaged over steps: on an expert mesh that shard sets the step.
         """
         if not self.track_padding:
             raise ValueError(
@@ -597,10 +632,18 @@ class ServingEngine:
         self.stats["padding_overhead"] = (
             self.stats["padded_model_rows"] / routed - 1.0
         )
+        self.stats["shard_rows_per_step"] = {
+            s: rows / steps
+            for s, rows in sorted(self.stats["shard_model_rows"].items())
+        }
+        self.stats["busiest_shard_rows_per_step"] = (
+            self.stats["busiest_shard_model_rows"] / steps
+        )
         return {
             k: self.stats[k]
             for k in ("padded_rows_per_step", "routed_rows_per_step",
-                      "padding_overhead")
+                      "padding_overhead", "shard_rows_per_step",
+                      "busiest_shard_rows_per_step")
         }
 
     # -- elastic membership -------------------------------------------------
@@ -1653,7 +1696,9 @@ def main() -> None:
         ps = engine.padding_stats()
         print(f"padding: padded_rows/step={ps['padded_rows_per_step']:.2f} "
               f"routed_rows/step={ps['routed_rows_per_step']:.2f} "
-              f"overhead={ps['padding_overhead']:.3f}")
+              f"overhead={ps['padding_overhead']:.3f} "
+              f"busiest_shard_rows/step="
+              f"{ps['busiest_shard_rows_per_step']:.2f}")
     if engine.elastic:
         print(engine.membership_line())
 

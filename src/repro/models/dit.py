@@ -370,13 +370,15 @@ def gather_expert_params(stacked, expert_idx: Array):
 # ---------------------------------------------------------------------------
 
 
-def _ragged_dense(leaf: dict, x: Array, pe: Array) -> Array:
+def _ragged_dense(leaf: dict, x: Array, pe: Array,
+                  live: Array | None = None) -> Array:
     """Per-pair expert dense through the one-kernel ragged GEMM.
 
     ``leaf`` is a ``{"w": ..., "b"?: ...}`` node of a store's
     ``ragged_view()``: weights stay raw (``QuantLeaf`` keeps int8/fp8
     bytes + scales all the way into the kernel's fused-dequant
     epilogue); the bias — tiny — expands through ``dequant_leaf``.
+    ``live`` (per pair) is ``ops.ragged_expert_matmul``'s.
     """
     w = leaf["w"]
     if isinstance(w, QuantLeaf):
@@ -385,7 +387,8 @@ def _ragged_dense(leaf: dict, x: Array, pe: Array) -> Array:
         wq, ws = w, None
     b = leaf.get("b")
     bias = None if b is None else dequant_leaf(b)
-    return ops.ragged_expert_matmul(x, wq, pe, bias=bias, w_scale=ws)
+    return ops.ragged_expert_matmul(x, wq, pe, bias=bias, w_scale=ws,
+                                    live=live)
 
 
 def _layer_view(tree, layer: int):
@@ -428,7 +431,7 @@ def make_ragged_expert_apply(cfg: DiTConfig):
 
     Signature::
 
-        ragged_apply_fn(view, x_p, t_p, cond_pg, expert_ids, g)
+        ragged_apply_fn(view, x_p, t_p, cond_pg, expert_ids, g, live=None)
 
     ``view`` = ``ExpertParamStore.ragged_view()``; ``x_p`` ``(P, H, W,
     C)`` one latent per routed pair; ``t_p`` ``(P,)``; ``cond_pg``
@@ -437,7 +440,10 @@ def make_ragged_expert_apply(cfg: DiTConfig):
     embedding, ``drop_mask`` rows substitute it per replica); returns
     ``(P·g, H, W, C)`` float32, pair-major (replicas of a pair
     adjacent).  Bitwise-identical to the grouped executor for dense
-    float32 params.
+    float32 params.  ``live`` ``(P,)`` bool, given only on an expert
+    mesh (``core.dispatch.shard_share``), marks the pairs whose
+    predictions are read: the ragged GEMMs skip the others' row tiles,
+    and their outputs are not meaningful.
     """
     if cfg.num_classes:
         raise ValueError(
@@ -445,14 +451,14 @@ def make_ragged_expert_apply(cfg: DiTConfig):
             "(num_classes > 0) goes through the dense apply"
         )
 
-    def ragged_apply(view, x_p, t_p, cond, pe, g):
+    def ragged_apply(view, x_p, t_p, cond, pe, g, live=None):
         p_pairs = x_p.shape[0]
         d = cfg.d_model
         hd = d // cfg.num_heads
         ps = cfg.patch_size
 
         def pd(leaf, x):
-            return _ragged_dense(leaf, x, pe)
+            return _ragged_dense(leaf, x, pe, live)
 
         xp = patchify(x_p.astype(cfg.activation_dtype), ps)
         h_r = pd(view["patch_embed"], xp)                  # (P, T, d)

@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import dataclasses
 import json
+import os
 from contextlib import nullcontext
 import re
 import sys
@@ -75,11 +76,30 @@ def _experts():
                        ragged_apply_fn=ragged) for i in range(K)]
 
 
-def _engine(params, sampler=None, **kw):
+def fixed_router(routes):
+    """A router that sends sample ``b`` to experts ``routes[b]`` (top-2)
+    at every step, whatever the latent."""
+    table = np.full((len(routes), K), -20.0, np.float32)
+    for b, (first, second) in enumerate(routes):
+        table[b, first], table[b, second] = 2.0, 1.0
+
+    def route(x, t):
+        return jax.nn.softmax(jnp.asarray(table)[:x.shape[0]], axis=-1)
+
+    return route
+
+
+#: every pair on shard 0 (experts 0 and 1)
+ONE_SHARD = [(0, 1)] * BATCH
+#: pairs split 4 : 1 : 1 : 2 over the shards (shard = expert // 2)
+UNEVEN = [(0, 1), (0, 2), (4, 6), (1, 7)]
+
+
+def _engine(params, sampler=None, route=router, **kw):
     sampler = sampler or SamplerConfig(num_steps=STEPS, cfg_scale=7.5,
                                        strategy="topk", top_k=2)
     return ServingEngine(experts=_experts(), expert_params=params,
-                         router_fn=router, latent_shape=LATENT,
+                         router_fn=route, latent_shape=LATENT,
                          sampler=sampler, **kw)
 
 
@@ -118,10 +138,14 @@ class per_pair_rows:
 
         self.real = real = ops.ragged_expert_matmul
 
-        def matmul(x, w, expert_ids, *, bias=None, w_scale=None):
+        def matmul(x, w, expert_ids, *, bias=None, w_scale=None,
+                   live=None):
             if x.ndim > 2 or w_scale is not None:
-                return real(x, w, expert_ids, bias=bias, w_scale=w_scale)
+                return real(x, w, expert_ids, bias=bias, w_scale=w_scale,
+                            live=live)
             y = jnp.einsum("pd,pdf->pf", x, w[expert_ids])
+            if live is not None:
+                y = jnp.where(live[:, None], y, 0.0)
             return y if bias is None else y + bias[expert_ids]
 
         ops.ragged_expert_matmul = matmul
@@ -150,6 +174,74 @@ def generate() -> dict:
                       .generate(KEY, _text(), BATCH))
     return {"gap": out, "finite": bool(np.isfinite(got).all()),
             "int8_gap": _gap(q_ep, q_one), "mesh": dict(ep.mesh.shape)}
+
+
+def one_shard_routing() -> dict:
+    """Every pair routed to one shard: the other shards' GEMMs skip every
+    row tile, and the latents still match the one-device engine's."""
+    params = _params()
+    route = fixed_router(ONE_SHARD)
+    out = {}
+    for rows in ("all_experts", "per_pair"):
+        with per_pair_rows() if rows == "per_pair" else nullcontext():
+            one = np.asarray(_engine(params, route=route).generate(
+                KEY, _text(), BATCH))
+            got = np.asarray(_engine(
+                _on_shard_devices(params), route=route,
+                n_expert_shards=SHARDS, n_data_shards=1).generate(
+                    KEY, _text(), BATCH))
+        out[rows] = _gap(got, one)
+    return {"gap": out}
+
+
+def skipping_is_bitwise() -> dict:
+    """The expert-parallel engine through the Pallas kernels (interpret
+    mode): skipping the pairs a shard does not own against computing
+    every pair and masking them, as before skipping."""
+    real = dispatch.shard_share
+
+    def compute_every_pair(apply_fn, *args):
+        return real(lambda *a, live=None: apply_fn(*a), *args)
+
+    params = _on_shard_devices(_params())
+    outs = {}
+    os.environ["REPRO_FORCE_PALLAS"] = "1"
+    try:
+        for name, share in (("skip", real), ("compute", compute_every_pair)):
+            dispatch.shard_share = share
+            outs[name] = np.asarray(_engine(
+                params, n_expert_shards=SHARDS, n_data_shards=1).generate(
+                    KEY, _text(), BATCH))
+    finally:
+        dispatch.shard_share = real
+        del os.environ["REPRO_FORCE_PALLAS"]
+    return {"equal": bool(np.array_equal(outs["skip"], outs["compute"])),
+            "gap": _gap(outs["skip"], outs["compute"])}
+
+
+def shard_rows() -> dict:
+    """The row counter (``track_padding``) on the expert mesh under a
+    fixed uneven routing, with what the plan makes each shard own."""
+    g, per = 2, K // SHARDS
+    owned = [0] * SHARDS
+    for pair in UNEVEN:
+        for e in pair:
+            owned[e // per] += 1
+    out = {"plan_rows_per_step": [n * g for n in owned],
+           "plan_busiest": max(owned) * g}
+    for name, kw in (("one_device", {}),
+                     ("sharded", {"n_expert_shards": SHARDS,
+                                  "n_data_shards": 1})):
+        params = _params() if not kw else _on_shard_devices(_params())
+        eng = _engine(params, route=fixed_router(UNEVEN), track_padding=True,
+                      **kw)
+        eng.generate(KEY, _text(), BATCH)
+        stats = eng.padding_stats()
+        stats["shard_rows_per_step"] = [
+            stats["shard_rows_per_step"][s]
+            for s in sorted(stats["shard_rows_per_step"])]
+        out[name] = stats
+    return out
 
 
 def rolling_tick() -> dict:
@@ -226,10 +318,21 @@ def placement() -> dict:
         D.stack_expert_params = real
 
 
-def shares() -> dict:
+def shares(pallas: bool = False) -> dict:
     """One step's ragged predictions: the shards' masked shares, summed on
-    the host, against the one-device ragged apply of all pairs."""
+    the host, against the one-device ragged apply of all pairs.  With
+    ``pallas`` both run the Pallas kernels in interpret mode, so the
+    shards' launches skip their dead row tiles, and the small-row
+    products per pair, so that both run the same products."""
     from repro.launch.mesh import make_expert_mesh
+
+    if pallas:
+        os.environ["REPRO_FORCE_PALLAS"] = "1"
+        try:
+            with per_pair_rows():
+                return shares()
+        finally:
+            del os.environ["REPRO_FORCE_PALLAS"]
 
     params = _params()
     stacked = as_store(D.stack_expert_params(params))
@@ -310,8 +413,12 @@ def place_span() -> dict:
 def main() -> int:
     checks = {"devices": lambda: {"count": jax.device_count()},
               "generate": generate, "rolling_tick": rolling_tick,
+              "one_shard_routing": one_shard_routing,
+              "shard_rows": shard_rows,
+              "skipping_is_bitwise": skipping_is_bitwise,
               "placement": placement, "place_span": place_span,
               "shares": shares,
+              "shares_pallas": lambda: shares(pallas=True),
               "compiled_step": compiled_step}
     out = {}
     for name, fn in checks.items():

@@ -59,6 +59,35 @@ def test_expert_parallel_rolling_tick_matches_one_device(four):
     assert got["all_experts"] <= 1e-4, got
 
 
+def test_routing_every_pair_to_one_shard_matches_one_device(four):
+    """The other three shards skip every row tile of the step."""
+    got = _got(four, "one_shard_routing")["gap"]
+    assert got["per_pair"] <= 1e-6, got
+    assert got["all_experts"] <= 1e-4, got
+
+
+def test_skipping_dead_tiles_is_bitwise(four):
+    """Through the Pallas kernels, skipping the pairs a shard does not own
+    gives the latents of computing every pair and masking the others."""
+    got = _got(four, "skipping_is_bitwise")
+    assert got["equal"], got
+
+
+def test_row_counter_reports_each_shards_owned_rows(four):
+    """On the expert mesh each shard counts its owned pairs × g; the
+    busiest shard's rows are the plan's busiest, and nothing is counted
+    beyond the routed rows.  One device is one shard that runs them all."""
+    got = _got(four, "shard_rows")
+    sharded, one = got["sharded"], got["one_device"]
+    assert sharded["shard_rows_per_step"] == got["plan_rows_per_step"]
+    assert sharded["busiest_shard_rows_per_step"] == got["plan_busiest"]
+    assert sharded["padding_overhead"] == 0.0
+    routed = sum(got["plan_rows_per_step"])
+    assert sharded["padded_rows_per_step"] == routed
+    assert one["shard_rows_per_step"] == [routed]
+    assert one["busiest_shard_rows_per_step"] == routed
+
+
 @pytest.mark.parametrize("store", ["dense", "int8", "elastic"])
 def test_store_leaves_live_on_their_shards_device(four, store):
     got = _got(four, "placement")[store]
@@ -88,6 +117,16 @@ def test_placement_is_a_host_span(four):
 def test_shards_shares_sum_to_the_one_device_predictions(four):
     got = _got(four, "shares")
     assert got["gap"] <= 1e-6, got
+    assert got["owners"] == [1] * len(got["owners"])
+    assert all(got["owner_is_shard"])
+
+
+def test_shards_pallas_shares_skip_dead_tiles_exactly(four):
+    """Through the Pallas kernel (interpret mode) each shard's launches
+    skip the row tiles of pairs it does not own; its owned pairs are
+    bitwise the one-device apply's."""
+    got = _got(four, "shares_pallas")
+    assert got["gap"] == 0.0, got
     assert got["owners"] == [1] * len(got["owners"])
     assert all(got["owner_is_shard"])
 

@@ -14,7 +14,11 @@ Acceptance gates for the ragged dispatch stack:
   (e) RaggedExecutor == GroupedExecutor bitwise on a real DiT ensemble
       (dense store; CFG drop_mask, stacked-null, and no-text variants)
       and within quantized bounds for int8/fp8 stores, end-to-end
-      through sample_ensemble.
+      through sample_ensemble;
+  (f) dead row tiles (``tile_live`` / ``live``): live tiles bitwise
+      equal to the unmasked launch, dead ones exactly zero, no
+      contraction and no input fetch for them; live groups bitwise on
+      the dense fallback too.
 """
 
 import re
@@ -38,7 +42,7 @@ from repro.core.conversion import ConversionConfig
 from repro.core.param_store import make_store
 from repro.kernels import ops
 from repro.kernels import ref as R
-from repro.kernels.ragged_gemm import ragged_gemm
+from repro.kernels.ragged_gemm import _dead_tile_sources, ragged_gemm
 from repro.models import dit as D
 from repro.models.config import dit_b2
 
@@ -205,6 +209,141 @@ def test_grid_steps_scale_with_rows_not_experts():
         counts.append(int(tiles.sum()))
     # one expert hit vs all hit vs 8× capacity: identical tile counts
     assert counts == [gm * gf] * 3
+
+
+# --- (f) dead row tiles --------------------------------------------------------
+
+#: row-tile liveness patterns over 8 tiles
+LIVE_PATTERNS = {
+    "all": [1] * 8,
+    "none": [0] * 8,
+    "alternating": [1, 0] * 4,
+    "run": [0, 0, 1, 1, 1, 0, 0, 0],
+}
+
+
+def _operands(storage, m, d, f, k, bm, seed=30):
+    te = jax.random.randint(jax.random.PRNGKey(seed), (m // bm,), 0, k)
+    if storage == "dense":
+        return (_rand((m, d), seed=seed + 1), _rand((k, d, f), seed=seed + 2),
+                te)
+    kx, ky = jax.random.split(jax.random.PRNGKey(seed + 3))
+    if storage == "int8":
+        x = jax.random.randint(kx, (m, d), -127, 128).astype(jnp.int8)
+        w = jax.random.randint(ky, (k, d, f), -127, 128).astype(jnp.int8)
+    else:
+        x = _rand((m, d), seed=seed + 1).astype(jnp.float8_e4m3fn)
+        w = _rand((k, d, f), seed=seed + 2).astype(jnp.float8_e4m3fn)
+    xs = jax.random.uniform(jax.random.PRNGKey(seed + 4), (m,)) + 0.01
+    ws = jax.random.uniform(jax.random.PRNGKey(seed + 5), (k,)) + 0.01
+    return x, w, te, xs, ws
+
+
+@pytest.mark.parametrize("pattern", sorted(LIVE_PATTERNS))
+@pytest.mark.parametrize("storage", ["dense", "int8", "fp8"])
+def test_ragged_gemm_dead_tiles_skip_the_contraction(storage, pattern):
+    """Live tiles are bitwise the unmasked launch's, dead tiles exactly
+    zero, and the tile map counts a contraction on the live tiles only."""
+    m, d, f, k, bm, bf = 128, 16, 256, 4, 16, 128
+    args = _operands(storage, m, d, f, k, bm)
+    live = jnp.array(LIVE_PATTERNS[pattern], jnp.int32)
+    ref = ragged_gemm(*args, block_m=bm, block_f=bf, interpret=True)
+    out, tiles = ragged_gemm(*args, tile_live=live, block_m=bm, block_f=bf,
+                             interpret=True, debug=True)
+    rows = np.repeat(np.asarray(live) > 0, bm)
+    np.testing.assert_array_equal(np.asarray(out)[rows],
+                                  np.asarray(ref)[rows])
+    assert not np.asarray(out)[~rows].any()
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(R.ref_ragged_gemm(*args, tile_live=live)),
+        rtol=1e-6, atol=1e-5)
+    np.testing.assert_array_equal(
+        np.asarray(tiles),
+        np.repeat(np.asarray(live)[:, None], f // bf, axis=1))
+
+
+def _fetches(live, gf):
+    """Input block fetches of a ``(row tile, lane tile)`` grid walk: the
+    pipeline fetches a block when its index differs from the previous
+    grid step's.  A tile's x block is ``src[i]``, its weight block's lane
+    index ``j``, or ``col[i]`` on a dead tile."""
+    src, col = (np.asarray(a) for a in _dead_tile_sources(
+        jnp.array(live, jnp.int32), gf))
+    blocks = [(int(src[i]), j if col[i] < 0 else int(col[i]))
+              for i in range(len(live)) for j in range(gf)]
+    x = sum(a[0] != b[0] for a, b in zip(blocks, blocks[1:])) + 1
+    w = sum(a != b for a, b in zip(blocks, blocks[1:])) + 1
+    return x, w
+
+
+@pytest.mark.parametrize("pattern", sorted(LIVE_PATTERNS))
+def test_dead_tiles_fetch_no_input_block(pattern):
+    """A dead tile points at blocks already in VMEM: the grid fetches
+    exactly the x and weight blocks of the live tiles alone (one of each
+    when none is live)."""
+    live, gf = LIVE_PATTERNS[pattern], 3
+    n_live = sum(live)
+    x, w = _fetches(live, gf)
+    assert (x, w) == ((n_live, n_live * gf) if n_live else (1, 1))
+
+
+@pytest.mark.parametrize("width", ["tiled", "narrow"])
+@pytest.mark.parametrize("storage", ["dense", "int8"])
+@pytest.mark.parametrize("force_pallas", ["1", "0"])
+def test_ragged_expert_matmul_live_contract(force_pallas, storage, width,
+                                            monkeypatch):
+    """``live`` keeps live groups bitwise the unmasked call's on the
+    Pallas path and the dense fallback (off-TPU, or groups too narrow to
+    tile).  The Pallas path skips dead groups (zero before the bias);
+    the fallback computes them as without ``live``."""
+    monkeypatch.setenv("REPRO_FORCE_PALLAS", force_pallas)
+    P, m, d, f, K = 6, 16 if width == "tiled" else 1, 32, 128, 4
+    x = _rand((P, m, d), seed=40)
+    w = _rand((K, d, f), seed=41)
+    b = _rand((K, f), seed=42)
+    kw = {}
+    if storage == "int8":
+        w, kw["w_scale"] = _quantize(w, "int8")
+    eids = jnp.array([0, 3, 1, 3, 2, 0], jnp.int32)
+    live = jnp.array([True, False, False, True, False, True])
+    ref = np.asarray(ops.ragged_expert_matmul(x, w, eids, bias=b, **kw))
+    out = np.asarray(ops.ragged_expert_matmul(x, w, eids, bias=b, live=live,
+                                              **kw))
+    keep = np.asarray(live)
+    np.testing.assert_array_equal(out[keep], ref[keep])
+    skipped = force_pallas == "1" and width == "tiled"
+    dead = (np.broadcast_to(np.asarray(b)[np.asarray(eids)][:, None],
+                            ref.shape) if skipped else ref)[~keep]
+    np.testing.assert_array_equal(out[~keep], dead)
+
+
+def _pallas_eqn(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            return eqn
+        for v in eqn.params.values():
+            inner = getattr(v, "jaxpr", None)
+            found = inner is not None and _pallas_eqn(
+                getattr(inner, "jaxpr", inner))
+            if found:
+                return found
+    return None
+
+
+def test_unmasked_launch_has_no_liveness_operand():
+    """Without ``tile_live`` the launch is the plain one: one prefetched
+    operand (the tile experts) and no branch in the kernel."""
+    x, w, te = _operands("dense", 64, 16, 128, 2, 16)
+    launches = {}
+    for name, kw in (("plain", {}),
+                     ("masked", {"tile_live": jnp.ones((4,), jnp.int32)})):
+        eqn = _pallas_eqn(jax.make_jaxpr(lambda *a: ragged_gemm(
+            *a, block_m=16, block_f=128, interpret=True, **kw))(x, w, te)
+            .jaxpr)
+        launches[name] = (
+            eqn.params["grid_mapping"].num_index_operands,
+            sum(q.primitive.name == "cond" for q in eqn.params["jaxpr"].eqns))
+    assert launches == {"plain": (1, 0), "masked": (3, 2)}
 
 
 def test_tile_misalignment_is_loud():

@@ -145,6 +145,37 @@ def test_ragged_gemm_compiles_for_v5e(one_chip, layer, storage):
     _assert_kernel(hlo, "ragged_gemm")
 
 
+#: the dit-xl2 denses that the expert-parallel cell runs with dead tiles
+XL2_LAYERS = ("xl2_attn_proj", "xl2_mlp_up", "xl2_mlp_down")
+
+
+@pytest.mark.parametrize("storage", ["f32", "int8"])
+@pytest.mark.parametrize("layer", XL2_LAYERS)
+def test_masked_ragged_gemm_compiles_for_v5e(one_chip, layer, storage):
+    """The dead-tile variant (``tile_live``: prefetched liveness, a branch
+    around the contraction, dead tiles' blocks re-pointed) compiles at
+    the dit-xl2 widths within the tile policy's VMEM budget."""
+    d, f = DENSE_SHAPES[layer]
+    x_dtype, w_dtype = STORAGE[storage]
+    quantized = storage == "int8"
+    x_bytes, w_bytes = jnp.dtype(x_dtype).itemsize, jnp.dtype(w_dtype).itemsize
+    bm, fp, bf = ops.ragged_tiles(_TOKENS, d, f, x_bytes, w_bytes, quantized)
+    assert ops._ragged_step_bytes(bm, d, bf, x_bytes, w_bytes, quantized) \
+        <= ops._RAGGED_VMEM_BUDGET
+    m = _GROUPS * _TOKENS
+    gm = m // bm
+    shapes = [((m, d), x_dtype), ((_K, d, fp), w_dtype), ((gm,), jnp.int32)]
+    if quantized:
+        shapes += [((m,), jnp.float32), ((_K,), jnp.float32)]
+    shapes.append(((gm,), jnp.int32))
+
+    def fn(*a):
+        return ragged_gemm(*a[:-1], tile_live=a[-1], block_m=bm,
+                           block_f=bf)
+
+    _assert_kernel(_compiled_hlo(fn, one_chip, *shapes), "ragged_gemm")
+
+
 def test_ragged_tiles_bound_deep_contraction_vmem():
     """The MLP down-projection's full-depth weight tile is what overflowed
     VMEM with a whole-width output block: the policy must narrow it."""
